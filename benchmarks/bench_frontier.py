@@ -124,7 +124,7 @@ def bench_end_to_end(D, engine: str, kmax: int, tau: int, reps: int = 2):
                 D,
                 KyivConfig(
                     tau=tau, kmax=kmax, engine=engine,
-                    device_frontier=frontier_on, interpret=True,
+                    device_frontier=frontier_on,
                 ),
             )
             for _ in range(max(1, reps))

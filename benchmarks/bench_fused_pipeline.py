@@ -49,7 +49,6 @@ def _mine_once(D, engine: str, fused: bool, kmax: int, tau: int) -> dict:
             kmax=kmax,
             engine=engine,
             fused_classify=fused,
-            interpret=True,
             # pin the host candidate path so this bench keeps isolating
             # classification fusion (device frontier vs host candidate gen
             # is benchmarks/bench_frontier.py's comparison)

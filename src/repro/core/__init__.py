@@ -10,6 +10,7 @@ from .placement import (
     HostPlacement,
     MeshPlacement,
     make_placement,
+    resolve_interpret,
     resolve_placement,
 )
 from .preprocess import Preprocessed, preprocess, ORDERINGS
@@ -48,6 +49,7 @@ __all__ = [
     "DevicePlacement",
     "MeshPlacement",
     "make_placement",
+    "resolve_interpret",
     "resolve_placement",
     "Preprocessed",
     "preprocess",

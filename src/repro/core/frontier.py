@@ -632,14 +632,12 @@ def _advance_device(
             # child bitsets stay on device: gather the store segment through
             # a power-of-two padded index (repeating row 0) so the gather
             # executable is shared across batches and levels
-            import jax.numpy as jnp
-
             from ..kernels.intersect.ops import next_bucket
 
             sb = next_bucket(n_store, 16)
             idx = np.zeros(sb, dtype=np.int32)
             idx[:n_store] = store_rows
-            new_children.append((child_d[jnp.asarray(idx)], n_store))
+            new_children.append((placement.frontier_take(child_d, idx), n_store))
 
     pending = None
     for lo, hi, n_pairs in iter_group_spans(sizes, batch_pairs):
@@ -731,7 +729,7 @@ def _advance_device(
             if len(new_children) == 1
             else jnp.concatenate([c for c, _ in new_children], axis=0)
         )
-        nxt_bits = big[jnp.asarray(np.concatenate(rows))]
+        nxt_bits = placement.frontier_take(big, np.concatenate(rows))
         owns = True
     else:
         nxt_itemsets = np.zeros((0, k), dtype=np.int32)

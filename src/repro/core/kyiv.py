@@ -162,7 +162,9 @@ class KyivConfig:
     # None derives a host/device placement from `engine` via one factory
     # (placement.resolve_placement). All placements are bit-identical.
     placement: Any = None
-    interpret: bool = True  # Pallas interpret mode (CPU container)
+    # Pallas interpret mode; None = decided by the backend (interpreted on
+    # CPU, compiled on TPU) — see core.placement.resolve_interpret
+    interpret: bool | None = None
     indexed_kernel: bool = True
     expansion: str = "full"  # "full" | "paper" (single-swap, Alg. 1 lines 36-38)
     seed: int = 0  # random-ordering seed

@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels.intersect import intersect as _kernels
 from ..kernels.intersect import ops as _ops
 from ..obs import cost as _obs_cost
 from ..obs import metrics as _om
@@ -75,8 +76,10 @@ __all__ = [
     "HostPlacement",
     "DevicePlacement",
     "MeshPlacement",
+    "is_compile_refusal",
     "is_device_failure",
     "make_placement",
+    "resolve_interpret",
     "resolve_placement",
     "set_fault_hook",
 ]
@@ -138,19 +141,58 @@ _DEVICE_FAILURE_MARKERS = (
     "DATA_LOSS",
 )
 
+# Substrings of the chip compiler's refusals. A kernel or program the
+# compiler rejects fails the same way on every retry, so it is a bug to
+# surface, never a device fault to degrade around — even though a
+# fast-memory refusal reads "RESOURCE_EXHAUSTED: Allocation (size=...)
+# would exceed memory (size=1048576) :: ... space=smem".
+_COMPILE_REFUSAL_MARKERS = (
+    "Mosaic failed to compile",
+    "compile permanent error",
+    "would exceed memory (size=",
+    "space=smem",
+    "space=vmem",
+)
+
+
+def is_compile_refusal(exc: BaseException) -> bool:
+    """Did the chip's compiler (or the Pallas lowering) refuse a program?"""
+    msg = str(exc)
+    return any(marker in msg for marker in _COMPILE_REFUSAL_MARKERS)
+
 
 def is_device_failure(exc: BaseException) -> bool:
     """Is ``exc`` a device/runtime failure worth retrying on, or degrading
     Device/Mesh -> Host placement for — as opposed to a bug that would fail
-    identically on the host? Injected faults mark themselves with an
-    ``is_device_failure`` attribute; real JAX runtime errors are classified
-    by type name and message."""
+    identically on the host or on every retry? Injected faults mark
+    themselves with an ``is_device_failure`` attribute; compile and lowering
+    refusals never count; real runtime faults are ``jax.errors.JaxRuntimeError``
+    (what jaxlib raises) or carry a runtime marker in their message."""
     if getattr(exc, "is_device_failure", False):
         return True
-    if type(exc).__name__ == "XlaRuntimeError":
+    if is_compile_refusal(exc):
+        return False
+    if isinstance(exc, jax.errors.JaxRuntimeError):
         return True
     msg = str(exc)
     return any(marker in msg for marker in _DEVICE_FAILURE_MARKERS)
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode, decided once from the backend: interpreted on
+    the CPU backend, compiled by Mosaic everywhere else. ``None`` takes the
+    backend's answer; an explicit value that disagrees raises, so an
+    interpreted kernel can never pass for the chip (nor a Mosaic kernel be
+    sent to the CPU)."""
+    backend = jax.default_backend()
+    resolved = backend == "cpu"
+    if interpret is not None and bool(interpret) != resolved:
+        raise ValueError(
+            f"interpret={interpret} on the {backend!r} backend: Pallas kernels "
+            f"are {'interpreted' if resolved else 'compiled'} there — leave "
+            "interpret unset"
+        )
+    return resolved
 
 
 @runtime_checkable
@@ -160,7 +202,8 @@ class BitsetPlacement(Protocol):
     ``kind`` names the placement ("host" / "device" / "mesh");
     ``store_word_tile`` is the word-count multiple stored bitset matrices
     must be padded to so :meth:`put_bits` never re-packs (1 for host and
-    single-device, the word-shard count on a mesh).
+    the jnp device engine, 128 lanes for the Pallas engine, the word-shard
+    count on a mesh).
     """
 
     kind: str
@@ -231,6 +274,11 @@ class BitsetPlacement(Protocol):
     def frontier_partition(self, classes):
         """One compaction pass over fused class codes: returns ``(order,
         n_emit, n_store)`` placement-native, segments in candidate order."""
+        ...
+
+    def frontier_take(self, bits, rows: np.ndarray):
+        """Gather stored child rows device-to-device into the layout the
+        next level's :meth:`prepare` expects."""
         ...
 
     def release(self, state: Any) -> None:
@@ -337,39 +385,62 @@ class DevicePlacement:
     Parent bitsets and popcounts upload once per level; every batch ships
     only the (tiny) padded pair list, and the bound dispatch callable is
     shared process-wide per bucket shape through ``ops.EXEC_CACHE``.
+
+    With the Pallas engine the word axis is padded to a multiple of 128
+    lanes (``store_word_tile``: the dataset store builds bitsets that way,
+    so only foreign callers pay a pad), and the indexed kernels read the
+    ``(t, 1, W)`` row layout (``kernels.intersect.intersect.as_rows``):
+    :meth:`prepare` converts a level once, and the kernels hand children
+    back in that layout so the next level chains without a copy.
+    ``interpret`` is resolved from the backend (:func:`resolve_interpret`).
     """
 
     kind = "device"
-    store_word_tile = 1
 
     def __init__(
         self,
         engine: str = "jnp",
         *,
-        interpret: bool = True,
+        interpret: bool | None = None,
         indexed: bool = True,
         block_pairs: int = 8,
-        block_words: int = 512,
+        block_words: int = 8192,
     ):
         if engine not in ("jnp", "pallas"):
             raise ValueError(f"DevicePlacement engine must be jnp|pallas, got {engine!r}")
         self.engine = engine
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.indexed = indexed
         self.block_pairs = block_pairs
         self.block_words = block_words
+        pallas = engine == "pallas"
+        self.store_word_tile = _ops.LANES if pallas else 1
+        self._row_layout = pallas and indexed
+        # scalar-prefetched pair tables must fit SMEM (LevelPipeline chunks)
+        self.max_dispatch_pairs = _ops.MAX_INDEXED_PAIRS if self._row_layout else None
         # gathered write path: donate the gathered operand on accelerator
         # backends so the child output aliases its buffer; CPU donation is
         # unsupported (warning + copy), so gate on backend.
         self.donate = jax.default_backend() in ("tpu", "gpu")
 
+    def _resident(self, bits):
+        """``(array, owned)``: bits on the device in this placement's layout
+        (lane-padded words, row layout for the indexed kernels); ``owned``
+        when this call created the array, so :meth:`release` may drop it."""
+        out = bits if isinstance(bits, jax.Array) else jnp.asarray(bits)
+        if self.engine == "pallas":
+            out = _ops.pad_words(out)
+            if self._row_layout:
+                out = _kernels.as_rows(out)
+        return out, out is not bits
+
     def prepare(self, bits, parent_counts, tau: int, *, fused_classify: bool):
-        owned = not isinstance(bits, jax.Array)  # fresh upload -> releasable
+        dev, owned = self._resident(bits)
         return (
-            jnp.asarray(bits),
+            dev,
             jnp.asarray(np.asarray(parent_counts), dtype=jnp.int32),
             jnp.int32(int(tau)),
-            int(bits.shape[1]),
+            int(dev.shape[-1]),
             fused_classify,
             owned,
         )
@@ -437,13 +508,13 @@ class DevicePlacement:
         return jnp.asarray(bits)
 
     def prepare_coverage(self, bits):
-        return jnp.asarray(bits)
+        return self._resident(bits)[0]
 
     def coverage_dispatch(self, state, padded_sets, padded_weights):
         _guard("coverage")
         from ..kernels.coverage import ops as _cov
 
-        n_words = int(state.shape[1])
+        n_words = int(state.shape[-1])
         bucket, width = int(padded_sets.shape[0]), int(padded_sets.shape[1])
         key = (
             "coverage",
@@ -527,6 +598,10 @@ class DevicePlacement:
 
         fn = _fops.partition  # module-level jit: re-traces per shape
         return fn(classes)
+
+    def frontier_take(self, bits, rows: np.ndarray):
+        """Gather stored child rows (device-to-device) for the next level."""
+        return bits[jnp.asarray(rows)]
 
     def release(self, state) -> None:
         """Retire a level eagerly: delete the device buffers this placement
@@ -627,6 +702,7 @@ class MeshPlacement:
         self._bits_sharding = NamedSharding(mesh, P(None, self.word_axis))
         self._pairs_sharding = NamedSharding(mesh, P(self.pair_axes, None))
         self._minp_sharding = NamedSharding(mesh, P(self.pair_axes))
+        self._repl_sharding = NamedSharding(mesh, P())
 
     def _put(self, arr, sharding):
         """Place one array under ``sharding`` — the process-spanning variant
@@ -751,9 +827,7 @@ class MeshPlacement:
         tile-aligned — the dataset store's layout — ship with zero re-packing
         copies, and jax arrays already on the mesh reshard in place."""
         if not isinstance(bits, jax.Array):
-            from .sharded import pad_words
-
-            bits = pad_words(np.ascontiguousarray(bits), self.word_shards)
+            bits = _ops.pad_words(np.ascontiguousarray(bits), self.word_shards)
         return self._put(bits, self._bits_sharding)
 
     def prepare_coverage(self, bits):
@@ -849,17 +923,34 @@ class MeshPlacement:
         ok = fn(state["ids"], state["keys"], pairs_sh, valid_sh)
         return pairs, ok
 
+    def _replicated(self, arr):
+        """Replicate a per-pair vector over the whole mesh. The frontier
+        mask/partition bodies index and scatter over the full batch; fed a
+        pair-sharded array, jit propagates its ``P(pair_axes)`` spec into a
+        scatter that has no mesh in scope and fails. Replicated inputs keep
+        those bodies plain single-program code on every device."""
+        if self.spans_processes:
+            return arr  # step bodies already all-gathered (replicate=True)
+        return jax.device_put(arr, self._repl_sharding)
+
     def frontier_mask(self, state, pairs, ok):
         from ..kernels.frontier import ops as _fops
 
         fn = _fops.mask_pruned  # module-level jit: re-traces per shape
-        return fn(jnp.asarray(pairs), jnp.asarray(ok))
+        return fn(self._replicated(pairs), self._replicated(ok))
 
     def frontier_partition(self, classes):
         from ..kernels.frontier import ops as _fops
 
         fn = _fops.partition  # module-level jit: re-traces per shape
-        return fn(jnp.asarray(classes))
+        return fn(self._replicated(classes))
+
+    def frontier_take(self, bits, rows: np.ndarray):
+        """Gather stored child rows on the mesh: reshard to the level-bits
+        layout (rows whole on every pair shard, words split) first, so the
+        row gather needs no collective and its output is already placed the
+        way the next level's :meth:`prepare` expects."""
+        return jax.device_put(bits, self._bits_sharding)[jnp.asarray(rows)]
 
     def release(self, state) -> None:
         """Eager level retirement on the mesh — same ownership rule as the
@@ -903,10 +994,10 @@ class MeshPlacement:
 def make_placement(
     engine: str,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     indexed: bool = True,
     block_pairs: int = 8,
-    block_words: int = 512,
+    block_words: int = 8192,
 ) -> BitsetPlacement:
     """Placement for an engine name: ``numpy``/``host`` -> host,
     ``jnp``/``pallas`` -> single device."""
@@ -940,6 +1031,6 @@ def resolve_placement(config) -> BitsetPlacement:
     engine = p if isinstance(p, str) else config.engine
     return make_placement(
         engine,
-        interpret=getattr(config, "interpret", True),
+        interpret=getattr(config, "interpret", None),
         indexed=getattr(config, "indexed_kernel", True),
     )

@@ -37,8 +37,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "sharded_level_step",
@@ -49,17 +49,7 @@ __all__ = [
     "sharded_frontier_support_step",
     "make_sharded_intersect",
     "make_sharded_pipeline",
-    "pad_words",
 ]
-
-
-def pad_words(bits: np.ndarray, multiple: int) -> np.ndarray:
-    """Pad the word dimension to a multiple (extra words are zero = no rows)."""
-    t, w = bits.shape
-    rem = (-w) % multiple
-    if rem == 0:
-        return bits
-    return np.concatenate([bits, np.zeros((t, rem), dtype=bits.dtype)], axis=1)
 
 
 # Word axes may be a single ICI axis name ("model") or a tuple of axis

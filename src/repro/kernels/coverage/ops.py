@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from ...core.exec_cache import exec_family
 from ...obs import metrics as _om
 from ...obs.trace import span as _obs_span
-from ..intersect.ops import _largest_divisor_tile
+from ..intersect.ops import SMEM_PREFETCH_WORDS, _largest_divisor_tile
 from . import coverage as _k
 from .ref import acc_to_record_counts, coverage_accumulate_ref
 
@@ -113,6 +113,12 @@ class CoverageEngine:
         self.max_batch_sets = max_batch_sets or max(
             256, (1 << 26) // max(self.n_words, 1)
         )
+        if getattr(placement, "max_dispatch_pairs", None):
+            # the indexed kernel prefetches the flattened (M * K,) set table
+            # and (M,) weights into SMEM: bound M like the intersect pairs,
+            # as a power of two so bucket padding stays inside the bound
+            fit = SMEM_PREFETCH_WORDS // (self.set_width + 1)
+            self.max_batch_sets = min(self.max_batch_sets, 1 << (fit.bit_length() - 1))
         self._state = placement.prepare_coverage(bits)
 
     def accumulate(

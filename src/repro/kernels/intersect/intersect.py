@@ -2,12 +2,15 @@
 
 Two data paths, each with a *write* and a *count-only* variant:
 
-1. **Indexed** (`*_indexed`): the pair list ``(M, 2)`` rides in scalar-prefetch
-   (SMEM); each grid step's BlockSpec ``index_map`` reads the pair indices and
-   DMAs exactly the two parent bitset rows it needs from HBM into VMEM. The
-   row *gather* is thereby fused into the block fetch — no gathered copy of
-   the parent level is ever materialised in HBM. This is the TPU analogue of
-   the paper's "intersection directly on the stored level".
+1. **Indexed** (`*_indexed`): the pair list rides in scalar-prefetch (SMEM,
+   flattened to ``(2M,)``); each grid step's BlockSpec ``index_map`` reads
+   the pair indices and DMAs exactly the two parent bitset rows it needs from
+   HBM into VMEM. The row *gather* is thereby fused into the block fetch — no
+   gathered copy of the parent level is ever materialised in HBM. This is the
+   TPU analogue of the paper's "intersection directly on the stored level".
+   Rows are read from the ``(t, 1, W)`` row layout (:func:`as_rows`) and
+   per-pair counts/classes leave as 128-lane rows: the only block shapes the
+   chip's compiler accepts for one row and one scalar per pair.
 
 2. **Gathered** (`*_gathered`): operates on pre-gathered ``(M, W)`` operand
    matrices with ``(block_pairs, block_words)`` VMEM tiles — the layout- and
@@ -22,9 +25,9 @@ this realises (and strengthens) the paper's "avoid the intersection at the
 last level": on TPU the expensive part is the HBM write, and it is gone.
 
 **Fused classify** (`*_classify_*`): the third pipeline stage. On top of the
-AND + popcount, these kernels take the parent popcounts (scalar-prefetch for
-the indexed path, a pre-gathered ``(M, 1)`` min-parent vector for the
-gathered path) plus the threshold ``τ`` and emit a per-pair **class code**
+AND + popcount, these kernels take the per-pair min parent popcount
+(scalar-prefetch for the indexed path, a pre-gathered ``(M, 1)`` VMEM vector
+for the gathered path) plus the threshold ``τ`` and emit a per-pair **class code**
 computed in VMEM on the final word-block of each pair:
 
   * ``CLASS_SKIP``  (0) — absent (``|R_W| = 0``) or uniform
@@ -39,8 +42,10 @@ that already holds the popcount, so the host only receives ``(M,)`` codes it
 can ``nonzero`` directly — the classify contract consumed by
 ``repro.core.kyiv`` when ``KyivConfig.fused_classify`` is on.
 
-All kernels run under ``interpret=True`` on CPU for validation; the BlockSpecs
-target real TPU VMEM tiling.
+On the CPU backend the kernels are interpreted; on a TPU Mosaic compiles
+them (``repro.core.placement.resolve_interpret`` decides, once). Word blocks
+are multiples of 128 lanes — callers pad W (``ops.pad_words``) — and
+``tests/test_tpu_compile.py`` compiles every variant for a described v5e.
 """
 
 from __future__ import annotations
@@ -55,6 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .ref import CLASS_EMIT, CLASS_SKIP, CLASS_STORE
 
 __all__ = [
+    "as_rows",
     "intersect_write_indexed",
     "intersect_count_indexed",
     "intersect_write_gathered",
@@ -70,31 +76,132 @@ _LANES = 128  # uint32 lanes per VPU register row
 _SUBLANES = 8
 
 
-def _write_indexed_kernel(idx_ref, a_ref, b_ref, child_ref, cnt_ref):
-    a = a_ref[0, :]
-    b = b_ref[0, :]
-    w = jnp.bitwise_and(a, b)
-    child_ref[0, :] = w
-    pc = jnp.sum(jax.lax.population_count(w).astype(jnp.int32))
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        cnt_ref[0, 0] = 0
-
-    cnt_ref[0, 0] += pc
+def _classify_scalar(cnt, minp, tau):
+    """Class codes for accumulated popcounts (elementwise: a lane row of
+    the indexed kernels or a (bm, 1) tile of the gathered ones)."""
+    skip = (cnt == 0) | (cnt == minp)
+    emit = jnp.logical_not(skip) & (cnt <= tau)
+    return jnp.where(skip, CLASS_SKIP, jnp.where(emit, CLASS_EMIT, CLASS_STORE)).astype(
+        jnp.int32
+    )
 
 
-def _count_indexed_kernel(idx_ref, a_ref, b_ref, cnt_ref):
-    w = jnp.bitwise_and(a_ref[0, :], b_ref[0, :])
-    pc = jnp.sum(jax.lax.population_count(w).astype(jnp.int32))
-    j = pl.program_id(1)
+def as_rows(bits: jax.Array) -> jax.Array:
+    """The indexed kernels' **row layout**: ``(t, W)`` -> ``(t, 1, W)``.
 
-    @pl.when(j == 0)
-    def _init():
-        cnt_ref[0, 0] = 0
+    Mosaic tiles the last two dims of a block by (8, 128), so a single-row
+    ``(1, bw)`` block of a 2-D ``(t, W)`` array is refused on the chip.
+    With the row axis leading, one row is the block ``(None, 1, bw)`` whose
+    last two dims are legal (1 equals the full dim, bw is a lane multiple).
+    The reshape is a relayout copy on the chip, so placements convert a
+    level once when it becomes resident — the kernels keep their child
+    output in this layout and the next level chains without a copy.
+    """
+    return bits if bits.ndim == 3 else bits.reshape(bits.shape[0], 1, bits.shape[1])
 
-    cnt_ref[0, 0] += pc
+
+def _row_spec(bw: int, col: int) -> pl.BlockSpec:
+    # one parent row per pair side, its index read from the flattened
+    # scalar-prefetched pair table (2m: I parent, 2m + 1: J parent)
+    return pl.BlockSpec((None, 1, bw), lambda m, j, idx, *_: (idx[2 * m + col], 0, j))
+
+
+# Per-pair scalars (count, class) leave the kernel as one 128-lane row per
+# pair: a (1, 1) block breaks the (8, 128) tiling rule and Mosaic cannot
+# store a scalar to VMEM, so the value is broadcast across the row and read
+# back at lane 0.
+_LANE_ROW_SPEC = pl.BlockSpec((None, 1, _LANES), lambda m, j, *_: (m, 0, 0))
+
+
+def _indexed_kernel(write: bool, classify: bool):
+    """Kernel body for one (pair, word-block) grid step.
+
+    The AND + popcount runs on the pair's two ``(1, bw)`` row blocks; the
+    count accumulates across word blocks in the pair's lane row, and the
+    fused variants classify on the final word block (``minp`` and ``tau``
+    ride in SMEM).
+    """
+
+    def kernel(*refs):
+        if classify:
+            _idx_ref, minp_ref, tau_ref, a_ref, b_ref, *outs = refs
+        else:
+            _idx_ref, a_ref, b_ref, *outs = refs
+        if write:
+            child_ref, *outs = outs
+        cnt_ref = outs[0]
+        m = pl.program_id(0)
+        j = pl.program_id(1)
+        w = jnp.bitwise_and(a_ref[...], b_ref[...])
+        if write:
+            child_ref[...] = w
+        pc = jnp.sum(jax.lax.population_count(w).astype(jnp.int32), axis=1, keepdims=True)
+
+        @pl.when(j == 0)
+        def _init():
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+        cnt_ref[...] += jnp.broadcast_to(pc, cnt_ref.shape)
+
+        if classify:
+            cls_ref = outs[1]
+
+            # classification runs once, on the pair's final word block, when
+            # the accumulated popcount is complete
+            @pl.when(j == pl.num_programs(1) - 1)
+            def _classify():
+                cls_ref[...] = _classify_scalar(cnt_ref[...], minp_ref[m], tau_ref[0])
+
+    return kernel
+
+
+def _indexed_call(bits, pairs, minp, tau, *, write, bw, interpret):
+    """Shared pallas_call of the four indexed variants.
+
+    ``bits`` is ``(t, W)`` or already in :func:`as_rows` layout; the child
+    comes back in the caller's layout. ``minp``/``tau`` are None for the
+    unfused variants. Scalar prefetch holds the flattened ``(2M,)`` pair
+    table (an ``(M, 2)`` table pads its last dim to 128 lanes in SMEM) plus,
+    fused, the ``(M,)`` per-pair min parent count and ``tau``.
+    """
+    rows = as_rows(bits)
+    _, _, W = rows.shape
+    M = pairs.shape[0]
+    if W % bw:
+        raise ValueError(f"W={W} not divisible by block_words={bw}")
+    classify = minp is not None
+    scalars = [pairs.astype(jnp.int32).reshape(-1)]
+    if classify:
+        scalars += [minp.astype(jnp.int32), jnp.asarray(tau, jnp.int32).reshape(1)]
+    out_specs, out_shape = [], []
+    if write:
+        out_specs.append(pl.BlockSpec((None, 1, bw), lambda m, j, *_: (m, 0, j)))
+        out_shape.append(jax.ShapeDtypeStruct((M, 1, W), rows.dtype))
+    for _ in range(2 if classify else 1):
+        out_specs.append(_LANE_ROW_SPEC)
+        out_shape.append(jax.ShapeDtypeStruct((M, 1, _LANES), jnp.int32))
+    outs = pl.pallas_call(
+        _indexed_kernel(write, classify),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(M, W // bw),
+            in_specs=[_row_spec(bw, 0), _row_spec(bw, 1)],
+            out_specs=out_specs,
+        ),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*scalars, rows, rows)
+    if write:
+        child, *outs = outs
+        if bits.ndim == 2:
+            child = child.reshape(M, W)
+        return (child, *(o[:, 0, 0] for o in outs))
+    return tuple(o[:, 0, 0] for o in outs)
+
+
+def _pair_minp(parent_counts, pairs):
+    pc = parent_counts.astype(jnp.int32)
+    return jnp.minimum(pc[pairs[:, 0]], pc[pairs[:, 1]])
 
 
 @functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
@@ -108,40 +215,16 @@ def intersect_write_indexed(
     """child = bits[pairs[:,0]] & bits[pairs[:,1]]; counts = popcount(child).
 
     Args:
-      bits: (t, W) uint32 parent-level bitsets in HBM. W % 128 == 0.
+      bits: (t, W) uint32 parent-level bitsets, or their (t, 1, W) row layout.
       pairs: (M, 2) int32 row indices.
-      block_words: word-dimension VMEM tile (multiple of 128).
+      block_words: word-dimension VMEM tile (a multiple of 128 dividing W).
     Returns:
-      (child (M, W) uint32, counts (M,) int32)
+      (child (M, W) uint32 — (M, 1, W) for row-layout input, counts (M,) int32)
     """
-    t, W = bits.shape
-    M = pairs.shape[0]
-    bw = min(block_words, W)
-    if W % bw:
-        raise ValueError(f"W={W} not divisible by block_words={bw}")
-    grid = (M, W // bw)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bw), lambda m, j, idx: (idx[m, 0], j)),
-            pl.BlockSpec((1, bw), lambda m, j, idx: (idx[m, 1], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bw), lambda m, j, idx: (m, j)),
-            pl.BlockSpec((1, 1), lambda m, j, idx: (m, 0)),
-        ],
-    )
-    child, cnt = pl.pallas_call(
-        _write_indexed_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((M, W), bits.dtype),
-            jax.ShapeDtypeStruct((M, 1), jnp.int32),
-        ],
+    return _indexed_call(
+        bits, pairs, None, None, write=True, bw=min(block_words, bits.shape[-1]),
         interpret=interpret,
-    )(pairs.astype(jnp.int32), bits, bits)
-    return child, cnt[:, 0]
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
@@ -153,30 +236,11 @@ def intersect_count_indexed(
     interpret: bool = False,
 ) -> jax.Array:
     """Count-only k=k_max path: popcount(bits[i] & bits[j]) with no HBM child write."""
-    t, W = bits.shape
-    M = pairs.shape[0]
-    bw = min(block_words, W)
-    if W % bw:
-        raise ValueError(f"W={W} not divisible by block_words={bw}")
-    grid = (M, W // bw)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bw), lambda m, j, idx: (idx[m, 0], j)),
-            pl.BlockSpec((1, bw), lambda m, j, idx: (idx[m, 1], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda m, j, idx: (m, 0)),
-        ],
-    )
-    cnt = pl.pallas_call(
-        _count_indexed_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((M, 1), jnp.int32)],
+    (cnt,) = _indexed_call(
+        bits, pairs, None, None, write=False, bw=min(block_words, bits.shape[-1]),
         interpret=interpret,
-    )(pairs.astype(jnp.int32), bits, bits)[0]
-    return cnt[:, 0]
+    )
+    return cnt
 
 
 def _write_gathered_kernel(a_ref, b_ref, child_ref, cnt_ref):
@@ -270,62 +334,6 @@ def intersect_count_gathered(
     return cnt[:, 0]
 
 
-# ---------------------------------------------------------------------------
-# Fused intersect + classify (Alg. 1 lines 31-41 in one device pass)
-# ---------------------------------------------------------------------------
-
-
-def _classify_scalar(cnt, minp, tau):
-    """Class code for one accumulated popcount (scalar / (bm,1) tile)."""
-    skip = (cnt == 0) | (cnt == minp)
-    emit = jnp.logical_not(skip) & (cnt <= tau)
-    return jnp.where(skip, CLASS_SKIP, jnp.where(emit, CLASS_EMIT, CLASS_STORE)).astype(
-        jnp.int32
-    )
-
-
-def _classify_write_indexed_kernel(
-    idx_ref, pc_ref, tau_ref, a_ref, b_ref, child_ref, cnt_ref, cls_ref
-):
-    m = pl.program_id(0)
-    j = pl.program_id(1)
-    w = jnp.bitwise_and(a_ref[0, :], b_ref[0, :])
-    child_ref[0, :] = w
-    pc = jnp.sum(jax.lax.population_count(w).astype(jnp.int32))
-
-    @pl.when(j == 0)
-    def _init():
-        cnt_ref[0, 0] = 0
-
-    cnt_ref[0, 0] += pc
-
-    # classification runs once, on the pair's final word-block, when the
-    # accumulated popcount is complete — the codes never leave VMEM/SMEM
-    # until this single int32 store.
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _classify():
-        minp = jnp.minimum(pc_ref[idx_ref[m, 0]], pc_ref[idx_ref[m, 1]])
-        cls_ref[0, 0] = _classify_scalar(cnt_ref[0, 0], minp, tau_ref[0])
-
-
-def _classify_count_indexed_kernel(idx_ref, pc_ref, tau_ref, a_ref, b_ref, cnt_ref, cls_ref):
-    m = pl.program_id(0)
-    j = pl.program_id(1)
-    w = jnp.bitwise_and(a_ref[0, :], b_ref[0, :])
-    pc = jnp.sum(jax.lax.population_count(w).astype(jnp.int32))
-
-    @pl.when(j == 0)
-    def _init():
-        cnt_ref[0, 0] = 0
-
-    cnt_ref[0, 0] += pc
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _classify():
-        minp = jnp.minimum(pc_ref[idx_ref[m, 0]], pc_ref[idx_ref[m, 1]])
-        cls_ref[0, 0] = _classify_scalar(cnt_ref[0, 0], minp, tau_ref[0])
-
-
 @functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
 def intersect_classify_write_indexed(
     bits: jax.Array,
@@ -339,49 +347,19 @@ def intersect_classify_write_indexed(
     """Fused child + popcount + class code, gather via scalar-prefetch.
 
     Args:
-      bits: (t, W) uint32 parent-level bitsets in HBM. W % block_words == 0.
+      bits: (t, W) uint32 parent-level bitsets, or their (t, 1, W) row layout.
       pairs: (M, 2) int32 row indices.
-      parent_counts: (t,) int32 parent popcounts |R_I| (rides in SMEM).
+      parent_counts: (t,) int32 parent popcounts |R_I|; the per-pair minimum
+        is gathered here and rides in SMEM, so SMEM use grows with the batch,
+        never with the level.
       tau: scalar int32 threshold (traced — one executable per bucket).
     Returns:
-      (child (M, W) uint32, counts (M,) int32, classes (M,) int32)
+      (child in the layout of ``bits``, counts (M,) int32, classes (M,) int32)
     """
-    t, W = bits.shape
-    M = pairs.shape[0]
-    bw = min(block_words, W)
-    if W % bw:
-        raise ValueError(f"W={W} not divisible by block_words={bw}")
-    grid = (M, W // bw)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bw), lambda m, j, idx, pc, tau: (idx[m, 0], j)),
-            pl.BlockSpec((1, bw), lambda m, j, idx, pc, tau: (idx[m, 1], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bw), lambda m, j, idx, pc, tau: (m, j)),
-            pl.BlockSpec((1, 1), lambda m, j, idx, pc, tau: (m, 0)),
-            pl.BlockSpec((1, 1), lambda m, j, idx, pc, tau: (m, 0)),
-        ],
+    return _indexed_call(
+        bits, pairs, _pair_minp(parent_counts, pairs), tau, write=True,
+        bw=min(block_words, bits.shape[-1]), interpret=interpret,
     )
-    child, cnt, cls = pl.pallas_call(
-        _classify_write_indexed_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((M, W), bits.dtype),
-            jax.ShapeDtypeStruct((M, 1), jnp.int32),
-            jax.ShapeDtypeStruct((M, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(
-        pairs.astype(jnp.int32),
-        parent_counts.astype(jnp.int32),
-        jnp.asarray(tau, jnp.int32).reshape(1),
-        bits,
-        bits,
-    )
-    return child, cnt[:, 0], cls[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
@@ -395,40 +373,10 @@ def intersect_classify_count_indexed(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused count-only k=k_max path: (counts, classes), no HBM child write."""
-    t, W = bits.shape
-    M = pairs.shape[0]
-    bw = min(block_words, W)
-    if W % bw:
-        raise ValueError(f"W={W} not divisible by block_words={bw}")
-    grid = (M, W // bw)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bw), lambda m, j, idx, pc, tau: (idx[m, 0], j)),
-            pl.BlockSpec((1, bw), lambda m, j, idx, pc, tau: (idx[m, 1], j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda m, j, idx, pc, tau: (m, 0)),
-            pl.BlockSpec((1, 1), lambda m, j, idx, pc, tau: (m, 0)),
-        ],
+    return _indexed_call(
+        bits, pairs, _pair_minp(parent_counts, pairs), tau, write=False,
+        bw=min(block_words, bits.shape[-1]), interpret=interpret,
     )
-    cnt, cls = pl.pallas_call(
-        _classify_count_indexed_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((M, 1), jnp.int32),
-            jax.ShapeDtypeStruct((M, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(
-        pairs.astype(jnp.int32),
-        parent_counts.astype(jnp.int32),
-        jnp.asarray(tau, jnp.int32).reshape(1),
-        bits,
-        bits,
-    )
-    return cnt[:, 0], cls[:, 0]
 
 
 def _classify_write_gathered_kernel(tau_ref, a_ref, b_ref, minp_ref, child_ref, cnt_ref, cls_ref):

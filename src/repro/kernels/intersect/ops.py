@@ -9,7 +9,8 @@ dispatches to one of the engines and strips padding:
   on numpy>=2.0, an exact ``unpackbits`` fallback otherwise); fastest on this
   CPU-only container, used by the wall-clock benchmarks.
 * ``jnp``    — the jnp oracle under jit (XLA CPU/TPU).
-* ``pallas`` — the Pallas kernels (``interpret=True`` on CPU; compiled on TPU).
+* ``pallas`` — the Pallas kernels (interpreted on the CPU backend, compiled by
+  Mosaic on the TPU — ``repro.core.placement.resolve_interpret`` decides).
 
 Two dispatch surfaces:
 
@@ -48,8 +49,6 @@ count, so callers never observe padding either way.
 """
 
 from __future__ import annotations
-
-import math
 
 import numpy as np
 
@@ -133,30 +132,60 @@ def _pad_pairs(pairs: np.ndarray, bucket: int) -> np.ndarray:
     return out
 
 
-def _largest_divisor_tile(dim: int, preferred: int) -> int:
-    """Largest tile <= preferred that divides dim, in O(sqrt(dim)).
+LANES = _k._LANES
 
-    The old implementation decremented from ``preferred`` until a divisor was
-    hit — O(dim) for prime word counts (a 4M-word prime spent milliseconds
-    here per dispatch). Fast paths: ``dim <= preferred`` and
-    ``gcd(dim, preferred) == preferred``; otherwise enumerate divisor pairs
-    up to sqrt(dim) and keep the largest <= preferred.
+# Scalar prefetch (SMEM) of one indexed dispatch holds the flattened pair
+# table (2 words per pair) plus the fused variants' per-pair min parent
+# count (1 word). v5e has 1 MiB of SMEM: a 2 MiB table was refused at
+# compile time (RESOURCE_EXHAUSTED, "prefetched SMEM operand"), and compile
+# time grows with the table, so one dispatch carries at most
+# MAX_INDEXED_PAIRS pairs — 192 KiB of SMEM. LevelPipeline splits larger
+# batches into chunks of this size.
+SMEM_PREFETCH_WORDS = 3 << 14
+MAX_INDEXED_PAIRS = SMEM_PREFETCH_WORDS // 3
+
+
+def pad_words(bits, multiple: int = LANES):
+    """Zero-pad the word axis (the last) to a multiple of ``multiple`` —
+    128 lanes for the Pallas kernels, the word-shard count on a mesh. Pad
+    words carry no rows, so every popcount is unchanged; numpy stays numpy,
+    device arrays pad on device."""
+    rem = (-int(bits.shape[-1])) % multiple
+    if rem == 0:
+        return bits
+    widths = [(0, 0)] * (bits.ndim - 1) + [(0, rem)]
+    return np.pad(bits, widths) if isinstance(bits, np.ndarray) else jnp.pad(bits, widths)
+
+
+def _largest_divisor_tile(dim: int, preferred: int) -> int:
+    """Largest word tile <= max(preferred, 128) that divides ``dim`` and is
+    a multiple of 128 lanes — the only tiles the chip's compiler accepts for
+    a word block. ``dim`` must itself be lane-aligned (:func:`pad_words`).
+
+    Enumerates divisor pairs of ``dim // 128`` up to its square root, so
+    prime word counts cost microseconds, not a linear scan.
     """
-    if dim <= preferred:
-        return max(dim, 1)
-    if preferred >= 1 and math.gcd(dim, preferred) == preferred:
-        return preferred
+    if dim % LANES:
+        raise ValueError(f"word count {dim} is not a multiple of {LANES} lanes")
+    n, cap = dim // LANES, max(preferred // LANES, 1)
+    if n <= cap:
+        return dim
     best = 1
     d = 1
-    while d * d <= dim:
-        if dim % d == 0:
-            if d <= preferred and d > best:
-                best = d
-            co = dim // d
-            if co <= preferred and co > best:
-                best = co
+    while d * d <= n:
+        if n % d == 0:
+            for c in (d, n // d):
+                if best < c <= cap:
+                    best = c
         d += 1
-    return best
+    return best * LANES
+
+
+def _pair_tile(bucket: int, block_pairs: int) -> int:
+    """Pair tile of the gathered kernels: ``block_pairs`` when it divides
+    the bucket (buckets are powers of two >= 256), else the whole batch —
+    both satisfy the compiler's sublane rule."""
+    return block_pairs if bucket % block_pairs == 0 else bucket
 
 
 def locality_order(pairs: np.ndarray) -> tuple[np.ndarray | None, np.ndarray | None]:
@@ -214,10 +243,10 @@ def intersect_and_count(
     *,
     write_children: bool,
     engine: str = "numpy",
-    interpret: bool = True,
+    interpret: bool | None = None,
     indexed: bool = True,
     block_pairs: int = 8,
-    block_words: int = 512,
+    block_words: int = 8192,
     pad_buckets: bool = True,
 ):
     """Compute ``child = bits[i] & bits[j]`` and/or ``counts = |child|``.
@@ -227,7 +256,8 @@ def intersect_and_count(
       pairs: (M, 2) integer row indices.
       write_children: False selects the count-only k=k_max path.
       engine: one of ``numpy`` / ``jnp`` / ``pallas``.
-      interpret: Pallas interpret mode (True on CPU).
+      interpret: Pallas interpret mode; None lets the backend decide
+        (``repro.core.placement.resolve_interpret``).
       indexed: Pallas path — scalar-prefetch gather (True) vs pre-gathered.
     Returns:
       (child (M, W) uint32 | None, counts (M,) int64 numpy array)
@@ -260,6 +290,10 @@ def intersect_and_count(
         else:
             child, cnt = None, _JIT_COUNT_REF(bits_j, pairs_j)
     else:  # pallas
+        from ...core.placement import resolve_interpret  # core imports kernels
+
+        interpret = resolve_interpret(interpret)
+        bits_j = pad_words(bits_j)
         W = bits_j.shape[1]
         bw = _largest_divisor_tile(W, block_words)
         if indexed:
@@ -275,7 +309,7 @@ def intersect_and_count(
         else:
             a = bits_j[pairs_j[:, 0]]
             b = bits_j[pairs_j[:, 1]]
-            bm = _largest_divisor_tile(bucket, block_pairs)
+            bm = _pair_tile(bucket, block_pairs)
             if write_children:
                 child, cnt = _k.intersect_write_gathered(
                     a, b, block_pairs=bm, block_words=bw, interpret=interpret
@@ -289,7 +323,7 @@ def intersect_and_count(
     counts = np.asarray(cnt)[:m].astype(np.int64)
     child_np = None
     if write_children:
-        child_np = np.asarray(child)[:m]
+        child_np = np.asarray(child)[:m, : bits.shape[1]]
     return child_np, counts
 
 
@@ -301,10 +335,10 @@ def intersect_classify(
     tau: int,
     write_children: bool,
     engine: str = "numpy",
-    interpret: bool = True,
+    interpret: bool | None = None,
     indexed: bool = True,
     block_pairs: int = 8,
-    block_words: int = 512,
+    block_words: int = 8192,
     pad_buckets: bool = True,
     locality_sort: bool = True,
 ):
@@ -432,7 +466,7 @@ def build_engine_dispatch(
         )
 
     # gathered pallas path
-    bm = _largest_divisor_tile(bucket, block_pairs)
+    bm = _pair_tile(bucket, block_pairs)
     if fused_classify:
         if write_children:
             kern = (
@@ -524,12 +558,12 @@ class LevelPipeline:
         tau: int,
         placement=None,
         engine: str | None = None,
-        interpret: bool = True,
+        interpret: bool | None = None,
         indexed: bool = True,
         fused_classify: bool = True,
         locality_sort: bool = True,
         block_pairs: int = 8,
-        block_words: int = 512,
+        block_words: int = 8192,
         pad_buckets: bool = True,
     ):
         if placement is None:
@@ -567,6 +601,46 @@ class LevelPipeline:
             if release is not None:
                 release(state)
 
+    def _dispatch(self, padded, write_children: bool):
+        """One placement dispatch per chunk of at most the placement's
+        ``max_dispatch_pairs`` (the SMEM bound of the indexed kernels, see
+        :data:`MAX_INDEXED_PAIRS`); chunk outputs are concatenated where
+        they live, so callers see one padded batch."""
+        cap = getattr(self.placement, "max_dispatch_pairs", None)
+        n = int(padded.shape[0])
+        if cap is None or n <= cap:
+            return self.placement.dispatch(self._state, padded, write_children)
+        parts = [
+            self.placement.dispatch(self._state, padded[s : s + cap], write_children)
+            for s in range(0, n, cap)
+        ]
+        cat = np.concatenate if isinstance(parts[0][1], np.ndarray) else jnp.concatenate
+        return tuple(
+            None if outs[0] is None else cat(outs, axis=0) for outs in zip(*parts)
+        )
+
+    def _materializer(self, child_d, cnt_d, cls_d, m: int, inverse=None):
+        n_words = self.n_words
+
+        def materialize():
+            counts = np.asarray(cnt_d)[:m].astype(np.int64)
+            child = None
+            if child_d is not None:
+                # row-layout (M, 1, W) children flatten for free on the host;
+                # lane/shard pad words are sliced off
+                child = np.asarray(child_d)
+                child = child.reshape(child.shape[0], -1)[:m, :n_words]
+            classes = np.asarray(cls_d)[:m].astype(np.int32) if cls_d is not None else None
+            if inverse is not None:
+                counts = counts[inverse]
+                if child is not None:
+                    child = child[inverse]
+                if classes is not None:
+                    classes = classes[inverse]
+            return child, counts, classes
+
+        return materialize
+
     def submit_padded(self, pairs, m: int, write_children: bool) -> BatchHandle:
         """Dispatch one *pre-padded* batch of device-generated pair indices.
 
@@ -578,16 +652,8 @@ class LevelPipeline:
         """
         _PIPE_BATCHES.inc(mode="padded")
         _PIPE_PAIRS.inc(int(pairs.shape[0]), mode="padded")
-        child_d, cnt_d, cls_d = self.placement.dispatch(self._state, pairs, write_children)
-        n_words = self.n_words
-
-        def materialize():
-            counts = np.asarray(cnt_d)[:m].astype(np.int64)
-            child = np.asarray(child_d)[:m, :n_words] if child_d is not None else None
-            classes = np.asarray(cls_d)[:m].astype(np.int32) if cls_d is not None else None
-            return child, counts, classes
-
-        return BatchHandle(materialize, raw=(child_d, cnt_d, cls_d))
+        raw = self._dispatch(pairs, write_children)
+        return BatchHandle(self._materializer(*raw, m), raw=raw)
 
     def submit(self, pairs: np.ndarray, write_children: bool) -> BatchHandle:
         """Dispatch one batch of pair intersections; non-blocking on device placements."""
@@ -609,22 +675,8 @@ class LevelPipeline:
                 pairs = pairs[order]
 
         padded = _pad_pairs(pairs, self.placement.padded_size(m, pad_buckets=self.pad_buckets))
-        child_d, cnt_d, cls_d = self.placement.dispatch(self._state, padded, write_children)
-        n_words = self.n_words
-
-        def materialize():
-            counts = np.asarray(cnt_d)[:m].astype(np.int64)
-            child = np.asarray(child_d)[:m, :n_words] if child_d is not None else None
-            classes = np.asarray(cls_d)[:m].astype(np.int32) if cls_d is not None else None
-            if inverse is not None:
-                counts = counts[inverse]
-                if child is not None:
-                    child = child[inverse]
-                if classes is not None:
-                    classes = classes[inverse]
-            return child, counts, classes
-
-        return BatchHandle(materialize)
+        raw = self._dispatch(padded, write_children)
+        return BatchHandle(self._materializer(*raw, m, inverse))
 
 
 class LegacyIntersectPipeline:

@@ -1,10 +1,8 @@
 # Fleet launch environment for serve_miner (source before launching).
 #
-# Allocator + XLA flag idiom for multi-host runs: tcmalloc for the
-# host-side bitset churn, latency-hiding scheduling and fat collective
-# combining for the DCN popcount psum. `repro.launch.mesh.launch_env_summary`
-# records the resulting environment into bench JSON rows so every perf
-# number names the flags that produced it.
+# Allocator settings for multi-host runs: tcmalloc for the host-side bitset
+# churn. `repro.launch.mesh.launch_env_summary` records the resulting
+# environment into bench JSON rows so every perf number names it.
 #
 # Usage:
 #   source launch/env.sh
@@ -19,11 +17,3 @@ fi
 # no numpy large-alloc warnings on multi-GB bitset matrices
 export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD=60000000000
 export TF_CPP_MIN_LOG_LEVEL=4
-
-# Overlap the word-axis popcount psum with the next pair gather, and combine
-# small DCN all-reduces into fat transfers (count vectors are per-batch and
-# tiny individually). Harmless no-ops off-GPU; TPU equivalents ride defaults.
-export XLA_FLAGS="${XLA_FLAGS:-} \
---xla_gpu_enable_latency_hiding_scheduler=true \
---xla_gpu_all_reduce_combine_threshold_bytes=134217728 \
---xla_gpu_all_gather_combine_threshold_bytes=1073741824"

@@ -18,6 +18,7 @@ from ..core.kyiv import mine_preprocessed
 from ..data.loaders import read_fimi
 from ..data.synth import DATASETS
 from ..distributed.checkpoint import CheckpointManager
+from .compile_cache import configure_compile_cache
 
 
 def main() -> None:
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None, help="write results JSON here")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.fimi:
         D = read_fimi(args.fimi)

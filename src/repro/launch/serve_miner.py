@@ -105,6 +105,7 @@ from ..service import (
     MiningService,
     NotReadyError,
 )
+from .compile_cache import configure_compile_cache
 
 __all__ = ["make_server", "main"]
 
@@ -532,6 +533,7 @@ def main() -> None:
     ap.add_argument("--flight-max-bytes", type=int, default=1 << 20,
                     help="on-disk bound for the flight event ring")
     args = ap.parse_args()
+    configure_compile_cache()
 
     obs_logs.setup(level=args.log_level, json_mode=args.log_json)
     _obs_tracer.configure(

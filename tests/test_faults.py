@@ -173,6 +173,101 @@ def test_breaker_cooldown_allows_device_recovery():
 
 
 # ---------------------------------------------------------------------------
+# Compile refusals are bugs: they propagate, they never degrade
+# ---------------------------------------------------------------------------
+
+# the chip compiler's refusal of an SMEM-overflowing scalar-prefetch table,
+# as jaxlib raises it (tests/test_tpu_compile.py provokes the real one)
+_SMEM_REFUSAL = (
+    "RESOURCE_EXHAUSTED: Allocation (size=2097152) would exceed memory "
+    "(size=1048576) :: #allocation3 [shape = 'u8[2097152]{0}', space=smem, "
+    "size = 0x200000, tag = 'prefetched SMEM operand 0'] :: intersect_count_indexed.1"
+)
+
+
+def test_compile_refusal_propagates_and_never_degrades():
+    import jax
+
+    data = _rand(5, 100, 5, 4)
+    inj = FaultInjector()
+    svc = MiningService.from_dataset(
+        data, engine="jnp", fault_injector=inj, resilience=FAST
+    )
+    with placement_faults(inj):
+        inj.arm(
+            "placement.dispatch",
+            exc=jax.errors.JaxRuntimeError(_SMEM_REFUSAL),
+            times=10_000,
+        )
+        with pytest.raises(jax.errors.JaxRuntimeError, match="space=smem"):
+            svc.mine(tau=2, kmax=3)
+        # raised on the first dispatch: no retry, no host answer
+        assert inj.fired("placement.dispatch") == 1
+    assert svc.device_retries == 0 and svc.degraded_mines == 0
+    assert svc.breaker.state == "closed"
+    assert svc.stats()["resilience"]["degraded_mines"] == 0
+    svc.close()
+
+
+def test_runtime_error_type_still_degrades():
+    """A real runtime fault (jaxlib's JaxRuntimeError, out of memory while
+    running) keeps the retry -> host degradation path."""
+    import jax
+
+    data = _rand(6, 100, 5, 4)
+    inj = FaultInjector()
+    svc = MiningService.from_dataset(
+        data, engine="jnp", fault_injector=inj, resilience=FAST
+    )
+    oom = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+        "allocate 1.00G. That was not possible. There are 512.00M free."
+    )
+    with placement_faults(inj):
+        inj.arm("placement.dispatch", exc=oom, times=10_000)
+        r = svc.mine(tau=2, kmax=3)
+    assert r.info.get("degraded") == "host"
+    assert svc.device_retries == FAST.max_retries and svc.degraded_mines == 1
+    assert _sets(r.result) == _sets(mine(data, KyivConfig(tau=2, kmax=3, engine="numpy")))
+    svc.close()
+
+
+@pytest.mark.parametrize(
+    "exc,device_failure",
+    [
+        (DeviceFault("injected"), True),
+        ("runtime-oom", True),
+        ("device-lost", True),
+        ("smem-refusal", False),
+        ("mosaic-refusal", False),
+        ("hbm-compile-refusal", False),
+        (ValueError("The Pallas TPU lowering currently requires that the last two "
+                    "dimensions of your block shape are divisible by 8 and 128"), False),
+        (KeyError("bug"), False),
+    ],
+    ids=["injected", "runtime-oom", "device-lost", "smem-refusal",
+         "mosaic-refusal", "hbm-compile-refusal", "lowering-refusal", "bug"],
+)
+def test_failure_classification(exc, device_failure):
+    import jax
+
+    from repro.core.placement import is_device_failure
+
+    real = {
+        "runtime-oom": "RESOURCE_EXHAUSTED: Out of memory while trying to allocate 8.00G.",
+        "device-lost": "INTERNAL: DEVICE_LOST: TPU chip 0 halted",
+        "smem-refusal": _SMEM_REFUSAL,
+        "mosaic-refusal": "INTERNAL: Mosaic failed to compile TPU kernel: Slice shape "
+        "along dimension 0 must be aligned to tiling (8), but is 1.",
+        "hbm-compile-refusal": "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. "
+        "Ran out of memory in memory space hbm. Used 17.2G of 15.75G hbm.",
+    }
+    if isinstance(exc, str):
+        exc = jax.errors.JaxRuntimeError(real[exc])
+    assert is_device_failure(exc) is device_failure
+
+
+# ---------------------------------------------------------------------------
 # Deadlines and cancellation
 # ---------------------------------------------------------------------------
 

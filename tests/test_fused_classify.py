@@ -183,23 +183,29 @@ def test_level_pipeline_empty_submit():
 
 
 def test_largest_divisor_tile():
-    """O(sqrt) divisor search agrees with the brute-force definition."""
+    """Word tiles are lane multiples: the O(sqrt) search agrees with the
+    brute-force definition, and unaligned word counts are refused."""
 
     def brute(dim, preferred):
-        t = min(preferred, dim)
+        t = max(preferred, 128) // 128 * 128
         while dim % t:
-            t -= 1
-        return max(t, 1)
+            t -= 128
+        return t
 
-    cases = [(512, 512), (384, 512), (1, 8), (7, 8), (12, 8), (128, 100),
-             (997, 512), (2 * 3 * 5 * 7 * 11, 100), (1 << 20, 512)]
+    cases = [(512, 512), (384, 512), (128, 8), (896, 512), (1536, 1024),
+             (128 * 997, 512), (128 * 2 * 3 * 5 * 7 * 11, 4096), (1 << 20, 512),
+             (31360, 8192), (31360, 512)]
     for dim, preferred in cases:
-        assert _largest_divisor_tile(dim, preferred) == brute(dim, preferred), (dim, preferred)
-    # pathological prime word counts: exact and instant
+        got = _largest_divisor_tile(dim, preferred)
+        assert got == brute(dim, preferred) and got % 128 == 0, (dim, preferred)
+    for unaligned in (1, 7, 31250, 31256):
+        with pytest.raises(ValueError):
+            _largest_divisor_tile(unaligned, 512)
+    # pathological prime lane counts: exact and instant
     import time
     big_prime = 1_000_003
     t0 = time.perf_counter()
-    assert _largest_divisor_tile(big_prime, 512) == 1
+    assert _largest_divisor_tile(128 * big_prime, 512) == 128
     assert time.perf_counter() - t0 < 0.05
 
 

@@ -62,6 +62,86 @@ def test_describe_is_json_friendly():
         json.dumps(d)  # /stats serialises this
 
 
+def test_interpret_resolves_from_backend(monkeypatch):
+    """Interpret mode is the backend's call: on CPU the Pallas kernels are
+    interpreted; on a TPU an explicit interpret=True is refused, never
+    quietly honoured."""
+    import jax
+
+    from repro.core import resolve_interpret
+
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret() is True and resolve_interpret(True) is True
+    assert DevicePlacement("pallas").interpret is True
+    assert make_placement("pallas").describe()["interpret"] is True
+    with pytest.raises(ValueError):
+        resolve_interpret(False)  # Mosaic kernels cannot run on the CPU
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    assert DevicePlacement("pallas").describe()["interpret"] is False
+    with pytest.raises(ValueError, match="interpret"):
+        DevicePlacement("pallas", interpret=True)
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_placement(KyivConfig(engine="pallas", interpret=True))
+
+
+@pytest.mark.parametrize("env_dir", [None, "set"], ids=["checkout", "env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """With JAX_COMPILATION_CACHE_DIR set nothing is configured in code;
+    without it the cache goes to one fixed, git-ignored checkout path."""
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    calls = []
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure_compile_cache() == str(tmp_path)
+        assert calls == []
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = compile_cache.configure_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_cache")
+        assert calls == [("jax_compilation_cache_dir", got)]
+        assert compile_cache.configure_compile_cache() == got  # fixed, not per call
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    assert jax.config.jax_compilation_cache_dir == prev
+
+
+def test_pallas_placement_lane_pads_and_bounds_dispatch():
+    """The Pallas placement pads words to 128 lanes (the store tiles for it),
+    keeps levels in the kernels' row layout, and LevelPipeline splits
+    batches above the SMEM bound into chunks — results unchanged."""
+    from repro.kernels.intersect.ops import MAX_INDEXED_PAIRS
+
+    p = DevicePlacement("pallas")
+    assert p.store_word_tile == 128 and p.max_dispatch_pairs == MAX_INDEXED_PAIRS
+    assert DevicePlacement("jnp").max_dispatch_pairs is None
+    assert DevicePlacement("pallas", indexed=False).max_dispatch_pairs is None
+    store = DatasetStore(3, word_tile=8, placement=p)
+    assert store.word_tile == 128
+
+    bits = RNG.integers(0, 2**32, size=(6, 5), dtype=np.uint32)  # 5 words
+    pc = np.bitwise_count(bits).sum(1)
+    pairs = RNG.integers(0, 6, size=(300, 2)).astype(np.int32)
+    want_child = bits[pairs[:, 0]] & bits[pairs[:, 1]]
+    p.max_dispatch_pairs = 128  # 300 pairs pad to 512 -> four dispatches
+    pipe = LevelPipeline(bits, pc, tau=40, placement=p)
+    assert pipe._state[0].shape == (6, 1, 128)  # lane-padded row layout
+    child, counts, classes = pipe.submit(pairs, True).result()
+    assert np.array_equal(child, want_child)
+    assert np.array_equal(counts, np.bitwise_count(want_child).sum(1))
+    host = LevelPipeline(bits, pc, tau=40, placement=HostPlacement())
+    assert np.array_equal(classes, host.submit(pairs, True).result()[2])
+
+
 # ---------------------------------------------------------------------------
 # mining equivalence: every placement is bit-identical to the host reference
 # ---------------------------------------------------------------------------
