@@ -1,0 +1,12 @@
+"""Level loop (``core/frontier.py``): self time of the program's
+``frontier.candidates`` spans (candidate generation, support test, bounds,
+masking), per mine."""
+
+from trace_reduce import self_time
+
+
+def read(run):
+    mines = [t for a in run.answers for t in a.trace_ids if t in run.spans]
+    if not mines:
+        return None
+    return sum(self_time(run.spans[t], "frontier.candidates") for t in mines) / len(mines)
