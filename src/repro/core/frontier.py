@@ -62,7 +62,6 @@ import numpy as np
 from ..kernels.intersect.ref import CLASS_EMIT, CLASS_STORE
 from ..obs import cost as _obs_cost
 from ..obs import metrics as _om
-from ..obs.trace import device_sync as _obs_device_sync
 from ..obs.trace import span as _obs_span
 from .bounds import apply_bounds
 from .placement import HostPlacement
@@ -84,7 +83,7 @@ _HOST_REFERENCE = HostPlacement()
 _LEVEL_SECONDS = _om.histogram(
     "repro_mine_level_seconds",
     "Per-level wall time by mining stage (candidates=gen+support+bounds, "
-    "intersect=dispatch+sync, classify=partition/consume, total).",
+    "intersect=dispatch+device waits, classify=partition/consume, total).",
     ("stage",),
 )
 _LEVEL_PAIRS = _om.counter(
@@ -238,26 +237,38 @@ def _device_frontier_capable(placement, pipe, config) -> bool:
     )
 
 
+def _wait(ls, site: str, fetch, *args):
+    """``fetch(*args)`` — a host read that blocks on the device — inside a
+    ``device.wait`` span named by the value fetched (``site``); the wait is
+    charged to the level's intersect clock."""
+    t0 = time.perf_counter()
+    with _obs_span("device.wait", site=site):
+        out = fetch(*args)
+    ls.time_intersect += time.perf_counter() - t0
+    return out
+
+
 def _emit_rows(results, ls, prep, expansion, lpos_mat, cnts) -> None:
     """Drain one batch's emitted minimal itemsets (vectorised; the per-item
     mirror expansion only runs for itemsets that touch a duplicate-rowset
     item, which is rare)."""
-    ids_mat = prep.l_items[lpos_mat]  # L-positions -> original item ids
-    ids_mat = np.sort(ids_mat, axis=1)  # canonical ascending ids
-    if prep.mirror_of:
-        mirror_items = np.fromiter(prep.mirror_of.keys(), dtype=np.int64)
-        has_mirror = np.isin(ids_mat, mirror_items).any(axis=1)
-    else:
-        has_mirror = np.zeros(ids_mat.shape[0], dtype=bool)
-    plain = ~has_mirror
-    results.extend(zip(map(tuple, ids_mat[plain].tolist()), cnts[plain].tolist()))
-    for r in np.nonzero(has_mirror)[0]:
-        results.extend(
-            expand_mirrors(
-                tuple(ids_mat[r].tolist()), int(cnts[r]), prep.mirror_of, expansion
+    with _obs_span("level.emit"):
+        ids_mat = prep.l_items[lpos_mat]  # L-positions -> original item ids
+        ids_mat = np.sort(ids_mat, axis=1)  # canonical ascending ids
+        if prep.mirror_of:
+            mirror_items = np.fromiter(prep.mirror_of.keys(), dtype=np.int64)
+            has_mirror = np.isin(ids_mat, mirror_items).any(axis=1)
+        else:
+            has_mirror = np.zeros(ids_mat.shape[0], dtype=bool)
+        plain = ~has_mirror
+        results.extend(zip(map(tuple, ids_mat[plain].tolist()), cnts[plain].tolist()))
+        for r in np.nonzero(has_mirror)[0]:
+            results.extend(
+                expand_mirrors(
+                    tuple(ids_mat[r].tolist()), int(cnts[r]), prep.mirror_of, expansion
+                )
             )
-        )
-    ls.emitted += ids_mat.shape[0]
+        ls.emitted += ids_mat.shape[0]
 
 
 def _candidate_lpos(frontier: LevelFrontier, pairs: np.ndarray) -> np.ndarray:
@@ -419,10 +430,7 @@ def _advance_host(
     def consume(entry):
         """Block on a dispatched batch and consume its classified output."""
         sel_itemsets, pairs, handle = entry
-        it0 = time.perf_counter()
-        with _obs_span("intersect.sync"):
-            child, counts, classes = handle.result()
-        ls.time_intersect += time.perf_counter() - it0
+        child, counts, classes = _wait(ls, "result", handle.result)
 
         with _obs_span("level.classify"):
             ct0 = time.perf_counter()
@@ -571,10 +579,7 @@ def _advance_device(
     def consume(entry):
         if entry[0] == "host":
             _, lpos, pairs, handle = entry
-            it0 = time.perf_counter()
-            with _obs_span("intersect.sync"):
-                child, counts, classes = handle.result()
-            ls.time_intersect += time.perf_counter() - it0
+            child, counts, classes = _wait(ls, "result", handle.result)
             with _obs_span("level.classify"):
                 ct0 = time.perf_counter()
                 inf_rows = np.nonzero(classes == CLASS_EMIT)[0]
@@ -589,11 +594,8 @@ def _advance_device(
             return
 
         _, mb, cpairs, n_ok_dev, handle = entry
-        it0 = time.perf_counter()
-        with _obs_span("intersect.sync"):
-            child_d, cnt_d, cls_d = handle.raw()
-            n_ok = int(n_ok_dev)  # first host sync of the batch
-        ls.time_intersect += time.perf_counter() - it0
+        child_d, cnt_d, cls_d = handle.raw()
+        n_ok = _wait(ls, "n_ok", int, n_ok_dev)  # first host sync of the batch
         ls.support_pruned += mb - n_ok
         ls.intersections += n_ok
         if n_ok == 0:
@@ -602,21 +604,19 @@ def _advance_device(
         with _obs_span("level.classify"):
             ct0 = time.perf_counter()
             order, n_emit_d, n_store_d = placement.frontier_partition(cls_d)
-            # the batch's bookkeeping arrays (segment order, pairs, counts)
-            # are a few ints per pair — fetch them whole and slice on the
-            # host, so the only per-batch device programs are the three
-            # jitted bucket-static ops (dispatch / mask / partition); a
-            # dynamically shaped device op per batch would recompile
-            # endlessly (SPMD programs on a mesh make that pathological)
-            order_h = np.asarray(order)
-            pairs_h = np.asarray(cpairs)
-            cnt_h = np.asarray(cnt_d).astype(np.int64)
-            n_emit, n_store = int(n_emit_d), int(n_store_d)
-            bucket = int(pairs_h.shape[0])
-            seg = bucket - n_emit - n_store  # skip segment incl. padding
-            # classify clock covers partition + fetches, not emission/store
-            # bookkeeping — mirroring the host path's historical attribution
             ls.time_classify += time.perf_counter() - ct0
+        # the batch's bookkeeping arrays (segment order, pairs, counts) are a
+        # few ints per pair — fetch them whole and slice on the host, so the
+        # only per-batch device programs are the three jitted bucket-static
+        # ops (dispatch / mask / partition); a dynamically shaped device op
+        # per batch would recompile endlessly (SPMD programs on a mesh make
+        # that pathological)
+        order_h = _wait(ls, "order", np.asarray, order)
+        pairs_h = _wait(ls, "pairs", np.asarray, cpairs)
+        cnt_h = _wait(ls, "counts", np.asarray, cnt_d).astype(np.int64)
+        n_emit = _wait(ls, "n_emit", int, n_emit_d)
+        n_store = _wait(ls, "n_store", int, n_store_d)
+        seg = int(pairs_h.shape[0]) - n_emit - n_store  # skip segment incl. padding
         ls.skipped_absent_uniform += n_ok - n_emit - n_store
 
         if n_emit:
@@ -650,15 +650,15 @@ def _advance_device(
             ct0 = time.perf_counter()
             pairs_d, ok_d = placement.frontier_dispatch(fstate, lo, hi, n_pairs)
             ls.time_candidates += time.perf_counter() - ct0
-            _obs_device_sync(pairs_d, ok_d)
 
         if host_bounds:
             # the one remaining host-assisted step: Lemma 4.6/Cor. 4.7 needs
             # the grandparent lookups, so survivors come to the host here
+            okh = _wait(ls, "ok", np.asarray, ok_d)
+            pairs_h = _wait(ls, "pairs", np.asarray, pairs_d)
             with _obs_span("frontier.candidates", phase="bounds"):
                 ct0 = time.perf_counter()
-                okh = np.asarray(ok_d)
-                pairs_h = np.asarray(pairs_d)[okh]
+                pairs_h = pairs_h[okh]
                 n_sup = int(okh.sum())
                 ls.support_pruned += n_pairs - n_sup
                 if n_sup == 0:

@@ -57,6 +57,7 @@ from ..kernels.intersect.ops import LegacyIntersectPipeline, LevelPipeline
 from ..obs import metrics as _om
 from ..obs.trace import span as _obs_span
 from ..obs.trace import start_trace as _obs_start_trace
+from ..obs.trace import watch_compiles as _obs_watch_compiles
 from .frontier import LevelFrontier, expand_mirrors, mine_levels
 from .items import ItemTable, itemize
 from .placement import resolve_placement
@@ -189,7 +190,7 @@ class LevelStats:
     skipped_absent_uniform: int = 0
     stored: int = 0
     time_total: float = 0.0
-    time_intersect: float = 0.0  # dispatch + blocking device sync
+    time_intersect: float = 0.0  # dispatch + host waits on device values
     time_classify: float = 0.0  # classification / partition consumption
     time_candidates: float = 0.0  # candidate gen + support test + bounds
     level_bytes: int = 0
@@ -208,18 +209,15 @@ class LevelStats:
 
     @property
     def time_host_busy(self) -> float:
-        """Host-side frontier work (candidate gen / support / bounds on the
-        host path; batch orchestration + emit drain on the device path)."""
+        """Host-side frontier work: candidate gen / support / bounds plus
+        classification (host path) or the partition dispatch (device path);
+        waits on the device are in ``time_intersect``."""
         return self.time_candidates + self.time_classify
 
-    @property
-    def time_device_busy(self) -> float:
-        """Time attributed to device dispatch + blocking sync."""
-        return self.time_intersect
-
     def timing_breakdown(self) -> dict:
-        """JSON-friendly per-level host-idle vs device-busy split (served in
-        ``/stats`` and recorded by the benchmarks)."""
+        """JSON-friendly per-level stage clocks on the host (served in
+        ``/stats`` and recorded by the benchmarks). Device time is not among
+        them: it comes from the profiler's trace."""
         return {
             "k": self.k,
             "total": self.time_total,
@@ -227,10 +225,6 @@ class LevelStats:
             "intersect": self.time_intersect,
             "classify": self.time_classify,
             "host_busy": self.time_host_busy,
-            "device_busy": self.time_device_busy,
-            "idle_other": max(
-                0.0, self.time_total - self.time_host_busy - self.time_device_busy
-            ),
         }
 
 
@@ -355,9 +349,11 @@ def mine_preprocessed(
 
     Every run records into :mod:`repro.obs`: a ``mine`` span (the trace
     root when no request trace is active, a child span otherwise) over
-    ``mine.seed`` + per-level ``mine.level`` children, plus the
-    ``repro_mine_*`` metric families.
+    ``mine.seed`` + per-level ``mine.level`` children (a ``jax.compile``
+    child wherever an executable is compiled), plus the ``repro_mine_*``
+    metric families.
     """
+    _obs_watch_compiles()
     with _obs_start_trace("mine") as _msp:
         try:
             result = _mine_preprocessed_inner(

@@ -114,5 +114,6 @@ def coverage_accumulate_indexed(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((32, W), jnp.int32)],
         interpret=interpret,
+        name="coverage_accumulate_indexed",
     )(sets.astype(jnp.int32).reshape(-1), weights.astype(jnp.int32), *([rows] * K))
     return acc

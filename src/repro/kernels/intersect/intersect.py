@@ -155,8 +155,9 @@ def _indexed_kernel(write: bool, classify: bool):
     return kernel
 
 
-def _indexed_call(bits, pairs, minp, tau, *, write, bw, interpret):
-    """Shared pallas_call of the four indexed variants.
+def _indexed_call(bits, pairs, minp, tau, *, name, write, bw, interpret):
+    """Shared pallas_call of the four indexed variants; ``name`` is the
+    kernel's name in the profiler's trace.
 
     ``bits`` is ``(t, W)`` or already in :func:`as_rows` layout; the child
     comes back in the caller's layout. ``minp``/``tau`` are None for the
@@ -190,6 +191,7 @@ def _indexed_call(bits, pairs, minp, tau, *, write, bw, interpret):
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*scalars, rows, rows)
     if write:
         child, *outs = outs
@@ -222,8 +224,8 @@ def intersect_write_indexed(
       (child (M, W) uint32 — (M, 1, W) for row-layout input, counts (M,) int32)
     """
     return _indexed_call(
-        bits, pairs, None, None, write=True, bw=min(block_words, bits.shape[-1]),
-        interpret=interpret,
+        bits, pairs, None, None, name="intersect_write_indexed", write=True,
+        bw=min(block_words, bits.shape[-1]), interpret=interpret,
     )
 
 
@@ -237,8 +239,8 @@ def intersect_count_indexed(
 ) -> jax.Array:
     """Count-only k=k_max path: popcount(bits[i] & bits[j]) with no HBM child write."""
     (cnt,) = _indexed_call(
-        bits, pairs, None, None, write=False, bw=min(block_words, bits.shape[-1]),
-        interpret=interpret,
+        bits, pairs, None, None, name="intersect_count_indexed", write=False,
+        bw=min(block_words, bits.shape[-1]), interpret=interpret,
     )
     return cnt
 
@@ -300,6 +302,7 @@ def intersect_write_gathered(
             jax.ShapeDtypeStruct((M, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="intersect_write_gathered",
     )(a, b)
     return child, cnt[:, 0]
 
@@ -330,6 +333,7 @@ def intersect_count_gathered(
         out_specs=[pl.BlockSpec((bm, 1), lambda i, j: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((M, 1), jnp.int32)],
         interpret=interpret,
+        name="intersect_count_gathered",
     )(a, b)[0]
     return cnt[:, 0]
 
@@ -357,7 +361,8 @@ def intersect_classify_write_indexed(
       (child in the layout of ``bits``, counts (M,) int32, classes (M,) int32)
     """
     return _indexed_call(
-        bits, pairs, _pair_minp(parent_counts, pairs), tau, write=True,
+        bits, pairs, _pair_minp(parent_counts, pairs), tau,
+        name="intersect_classify_write_indexed", write=True,
         bw=min(block_words, bits.shape[-1]), interpret=interpret,
     )
 
@@ -374,7 +379,8 @@ def intersect_classify_count_indexed(
 ) -> tuple[jax.Array, jax.Array]:
     """Fused count-only k=k_max path: (counts, classes), no HBM child write."""
     return _indexed_call(
-        bits, pairs, _pair_minp(parent_counts, pairs), tau, write=False,
+        bits, pairs, _pair_minp(parent_counts, pairs), tau,
+        name="intersect_classify_count_indexed", write=False,
         bw=min(block_words, bits.shape[-1]), interpret=interpret,
     )
 
@@ -456,6 +462,7 @@ def _intersect_classify_write_gathered(
             jax.ShapeDtypeStruct((M, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="intersect_classify_write_gathered",
     )(
         jnp.asarray(tau, jnp.int32).reshape(1),
         a,
@@ -520,6 +527,7 @@ def intersect_classify_count_gathered(
             jax.ShapeDtypeStruct((M, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="intersect_classify_count_gathered",
     )(
         jnp.asarray(tau, jnp.int32).reshape(1),
         a,

@@ -96,6 +96,7 @@ def intersect_count_tiled(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((T, bm, bm), jnp.int32)],
         interpret=interpret,
+        name="intersect_count_tiled",
     )(tile_i.astype(jnp.int32), tile_j.astype(jnp.int32), bits, bits)[0]
     return cnt
 

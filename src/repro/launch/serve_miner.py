@@ -163,9 +163,14 @@ class MinerHandler(BaseHTTPRequestHandler):
             self.http_stats[key] = self.http_stats.get(key, 0) + 1
 
     def _send(self, code: int, payload: dict) -> None:
+        self._write(code, self._encode(payload))
+
+    def _encode(self, payload: dict) -> bytes:
         if self._trace_id and isinstance(payload, dict):
             payload.setdefault("trace_id", self._trace_id)
-        body = json.dumps(payload).encode()
+        return json.dumps(payload).encode()
+
+    def _write(self, code: int, body: bytes) -> None:
         self._last_code = code
         self.send_response(code)
         self.send_header("Content-Type", "application/json")
@@ -291,17 +296,20 @@ class MinerHandler(BaseHTTPRequestHandler):
             code = 499 if resp.source == "partial" else 200
             if code == 499:
                 self._count("deadline_exceeded")
-            # itemset decode + JSON encode is real wall time on a cold mine;
-            # span it so the trace tree accounts for the full request
+            # itemset decode, JSON encode and the socket write are real
+            # wall time on a cold mine; span each so the trace tree accounts
+            # for the full request
             with _obs_span("http.respond"):
-                self._send(
-                    code,
-                    resp.to_json(
+                with _obs_span("http.decode"):
+                    doc = resp.to_json(
                         max_itemsets=int(max_itemsets)
                         if max_itemsets is not None
                         else None
-                    ),
-                )
+                    )
+                with _obs_span("http.encode"):
+                    body = self._encode(doc)
+                with _obs_span("http.write"):
+                    self._write(code, body)
         elif route == "/cancel":
             self._send(
                 200,

@@ -6,8 +6,9 @@ to know **which request** was expensive and **why**. A
 ``contextvars.copy_context()`` hop the tracer uses across the scheduler's
 worker thread), and the existing span seams fold their counters into it:
 ``core/frontier.py`` adds per-level candidate pairs / rows scanned / bytes,
-``core/placement.py`` adds device dispatches, the service adds
-compile-vs-reuse executable deltas and the cache path taken. The finished
+``core/placement.py`` adds device dispatches, JAX's own compile events
+count executables compiled (``obs.trace.watch_compiles``), the service adds
+executable reuse and the cache path taken. The finished
 envelope is attached to every ``/mine`` response under ``info.cost``,
 observed into per-path histogram families, and — when wall time crosses
 ``--slow-mine-threshold-s`` — appended to the ring-buffered

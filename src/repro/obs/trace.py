@@ -1,4 +1,4 @@
-"""Structural tracing for mining requests (stdlib only — this module is a
+"""Structural tracing for mining requests (stdlib only — this package is a
 leaf).
 
 A :class:`Trace` is one request's tree of :class:`Span` intervals
@@ -15,21 +15,30 @@ context-variable read, so library callers that never start a trace pay
 nothing. Finished traces land in a ring buffer (:meth:`Tracer.last` /
 :meth:`Tracer.get`) served by ``GET /trace``.
 
-Optional device-sync timing: :func:`device_sync` blocks on device arrays
-inside a span *only* when ``TRACER.sync_devices`` is enabled, so a span's
-wall time then includes the device work it dispatched (off by default —
-syncing defeats the double-buffered pipeline and is a debugging mode).
+While a ``jax.profiler`` session records, every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so the span tree lies on the
+host plane of the profiler's own trace beside the device operations (one
+line per thread). Without a session that costs one ``is_enabled()`` call a
+span, and only once the process has imported ``jax``.
+
+:func:`watch_compiles` (called by code that runs jax, and the one place
+this module imports it) turns every JAX backend compile into a finished
+``jax.compile`` span under the span open on the compiling thread, and
+counts it into the request's cost envelope.
 """
 
 from __future__ import annotations
 
 import contextvars
 import itertools
+import sys
 import threading
 import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
+
+from . import cost as _cost
 
 __all__ = [
     "Span",
@@ -40,7 +49,7 @@ __all__ = [
     "start_trace",
     "current_trace_id",
     "current_span",
-    "device_sync",
+    "watch_compiles",
 ]
 
 _CTX: "contextvars.ContextVar[tuple | None]" = contextvars.ContextVar(
@@ -48,6 +57,26 @@ _CTX: "contextvars.ContextVar[tuple | None]" = contextvars.ContextVar(
 )  # (Trace, Span) of the innermost open span
 
 _ids = itertools.count(1)
+
+# jax.profiler.TraceAnnotation, looked up once jax has been imported
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation`` called ``name`` while a profiler
+    session records, else None."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        cls = _ANNOTATION = jax.profiler.TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
 
 
 def _new_span_id() -> str:
@@ -170,7 +199,6 @@ class Tracer:
         self._lock = threading.Lock()
         self._traces: deque[Trace] = deque(maxlen=max_traces)
         self.sample_every = max(1, int(sample_every))
-        self.sync_devices = False
         self._started = 0
         self._sampled_out = 0
         self._appended = 0  # monotone: doubles as the per-trace seq cursor
@@ -182,15 +210,12 @@ class Tracer:
         self._listeners: list = []
 
     def configure(self, *, max_traces: int | None = None,
-                  sample_every: int | None = None,
-                  sync_devices: bool | None = None) -> None:
+                  sample_every: int | None = None) -> None:
         with self._lock:
             if max_traces is not None:
                 self._traces = deque(self._traces, maxlen=max(1, int(max_traces)))
             if sample_every is not None:
                 self.sample_every = max(1, int(sample_every))
-            if sync_devices is not None:
-                self.sync_devices = bool(sync_devices)
 
     # -- listeners -----------------------------------------------------------
 
@@ -235,12 +260,15 @@ class Tracer:
         root = Span(trace.trace_id, None, name)
         trace.root = root
         token = _CTX.set((trace, root))
+        ann = _annotation(name)
         if self._listeners:
             self._notify("open", root, trace)
         try:
             yield root
         finally:
             root.t1 = time.perf_counter()
+            if ann is not None:
+                ann.__exit__(None, None, None)
             trace.add(root)
             _CTX.reset(token)
             if self._listeners:
@@ -264,12 +292,15 @@ class Tracer:
         trace, parent = ctx
         sp = Span(trace.trace_id, parent.span_id, name, attrs)
         token = _CTX.set((trace, sp))
+        ann = _annotation(name)
         if self._listeners:
             self._notify("open", sp, trace)
         try:
             yield sp
         finally:
             sp.t1 = time.perf_counter()
+            if ann is not None:
+                ann.__exit__(None, None, None)
             trace.add(sp)
             _CTX.reset(token)
             if self._listeners:
@@ -313,7 +344,6 @@ class Tracer:
                 "appended": self._appended,
                 "dropped": self._dropped,
                 "sample_every": self.sample_every,
-                "sync_devices": self.sync_devices,
             }
 
     def reset(self) -> None:
@@ -340,16 +370,36 @@ def current_span() -> "Span | _NullSpan":
     return ctx[1] if ctx is not None else _NULL_SPAN
 
 
-def device_sync(*arrays) -> bool:
-    """Block until the given device arrays are ready — only when tracing
-    with ``TRACER.sync_devices`` on, so the enclosing span's wall time
-    includes the dispatched device work. Returns True if it synced."""
-    if not TRACER.sync_devices or _CTX.get() is None:
-        return False
-    try:
-        import jax
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_watch_lock = threading.Lock()
+_watching = False
 
-        jax.block_until_ready([a for a in arrays if a is not None])
-        return True
-    except Exception:
-        return False
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    """jax.monitoring listener: JAX calls it on the compiling thread once
+    an executable is compiled (or loaded from the persistent cache)."""
+    if event != _COMPILE_EVENT:
+        return
+    _cost.add(executables_compiled=1)
+    ctx = _CTX.get()
+    if ctx is None:
+        return
+    trace, parent = ctx
+    sp = Span(trace.trace_id, parent.span_id, "jax.compile",
+              {"fun_name": kw.get("fun_name")})
+    sp.t1 = sp.t0
+    sp.t0 -= float(duration)
+    trace.add(sp)
+
+
+def watch_compiles() -> None:
+    """Register :func:`_on_compile` with ``jax.monitoring`` (once per
+    process). Imports jax: call it from code that runs jax."""
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _watching = True

@@ -46,6 +46,7 @@ from ..obs.trace import TRACER as _obs_tracer
 from ..obs.trace import current_trace_id as _obs_current_trace_id
 from ..obs.trace import span as _obs_span
 from ..obs.trace import start_trace as _obs_start_trace
+from ..obs.trace import watch_compiles as _obs_watch_compiles
 from ..distributed.checkpoint import CheckpointManager
 from ..kernels.intersect import LevelPipeline
 from ..sampling import SamplingConfig, build_sample, classify_counts
@@ -237,6 +238,9 @@ class MiningService:
         flight_max_bytes: int = 1 << 20,
         **config_kw,
     ):
+        # executables_compiled in each request's cost envelope, and the
+        # jax.compile spans, come from JAX's own compile events
+        _obs_watch_compiles()
         self.config = config or KyivConfig(**config_kw)
         if placement is not None:
             self.config = dataclasses.replace(self.config, placement=placement)
@@ -709,8 +713,9 @@ class MiningService:
         if control is not None:
             with self._lock:
                 self._controls[key] = control
-        # compile-vs-reuse attribution: the envelope rode the context copy
-        # into this worker thread (same object the submitter holds)
+        # executable-reuse attribution: the envelope rode the context copy
+        # into this worker thread (same object the submitter holds); JAX's
+        # own compile events count executables_compiled (obs.trace)
         _env = _obs_cost.current()
         _xs0 = exec_cache.stats() if _env is not None else None
         try:
@@ -790,8 +795,8 @@ class MiningService:
             # ring can name the active requests at death
             with _obs_span("mine.cold", version=version, key=list(key)):
                 result, info = self._mine_cold(key, table, config, control)
-            # per-level host-busy vs device-busy split of the last cold run —
-            # the /stats view of what the device frontier buys per level
+            # per-level stage clocks of the last cold run — the /stats view
+            # of where a level's host time goes
             self._last_mine_timing = {
                 "version": version,
                 "tau": tau,
@@ -817,9 +822,6 @@ class MiningService:
             if _env is not None and _xs0 is not None:
                 _xs1 = exec_cache.stats()
                 _env.add(
-                    executables_compiled=max(
-                        0, _xs1.get("misses", 0) - _xs0.get("misses", 0)
-                    ),
                     executables_reused=max(
                         0, _xs1.get("hits", 0) - _xs0.get("hits", 0)
                     ),
@@ -1535,8 +1537,8 @@ class MiningService:
             # buckets (intersect / coverage / frontier) — per-family
             # counters under "families", process totals at the top level
             "executables": exec_cache.stats(),
-            # per-level timing split of the most recent cold mine (host
-            # candidate/classify work vs device dispatch+sync)
+            # per-level stage clocks of the most recent cold mine (host
+            # candidate/classify work vs dispatch + device waits)
             "last_mine": self._last_mine_timing,
             # registry fold-in: every metric family in one consistent
             # (single-lock) snapshot, plus the tracer's ring-buffer state.

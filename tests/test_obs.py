@@ -11,7 +11,11 @@ Covers the observability contracts:
 * the un-tearable ``/stats``/scrape snapshot with a mine in flight,
 * HTTP: ``/metrics`` (>= 20 families, lint-clean), ``X-Trace-Id``
   correlation, ``GET /trace``, JSON logs carrying the trace id,
-* the opt-in profiling hook.
+* the opt-in profiling hook,
+* host waits on the device in ``device.wait`` and nowhere else, emission in
+  ``level.emit``, the reply's decode / encode / write, compiles as
+  ``jax.compile`` spans and cost-envelope counts, and the span tree on the
+  profiler's own trace.
 """
 
 import io
@@ -41,7 +45,7 @@ def _rand(seed, n, m, dom=5):
 def tracer_reset():
     """Restore the process-wide tracer's config + ring buffer after a test."""
     yield TRACER
-    TRACER.configure(max_traces=64, sample_every=1, sync_devices=False)
+    TRACER.configure(max_traces=64, sample_every=1)
     TRACER.reset()
 
 
@@ -309,7 +313,7 @@ def _check_spans_against_stats(result, trace):
         ),
         "intersect": sum(
             s.duration
-            for s in trace.find("intersect.dispatch") + trace.find("intersect.sync")
+            for s in trace.find("intersect.dispatch") + trace.find("device.wait")
         ),
         "classify": sum(s.duration for s in trace.find("level.classify")),
     }
@@ -652,3 +656,241 @@ def test_profile_without_dump_dir_is_gauges_only():
     assert reg.counter(
         "repro_profile_runs_total", "", ("profiler",)
     ).value(profiler="off") == 1
+
+
+# ---------------------------------------------------------------------------
+# device waits, emission, the reply's cost, compiles, profiler annotations
+# ---------------------------------------------------------------------------
+
+_DELAY_S = 0.08
+
+
+class _SlowFetch:
+    """A device value whose host read (``np.asarray`` / ``int``) sleeps
+    first: a stand-in for a kernel the host has to wait for."""
+
+    def __init__(self, value, fetches):
+        self.value = value
+        self.fetches = fetches
+
+    def _wait(self):
+        import time
+
+        self.fetches.append(1)
+        time.sleep(_DELAY_S)
+
+    def __array__(self, dtype=None, copy=None):
+        self._wait()
+        return np.asarray(self.value, dtype=dtype)
+
+    def __int__(self):
+        self._wait()
+        return int(self.value)
+
+
+def _unwrap(v):
+    return v.value if isinstance(v, _SlowFetch) else v
+
+
+def _slow_device_placement(fetches):
+    """A jnp placement whose frontier outputs are read back slowly."""
+    from repro.core.placement import DevicePlacement
+
+    p = DevicePlacement("jnp")
+    dispatch, mask, partition = p.frontier_dispatch, p.frontier_mask, p.frontier_partition
+
+    def frontier_dispatch(*a, **kw):
+        pairs_d, ok_d = dispatch(*a, **kw)
+        return _SlowFetch(pairs_d, fetches), _SlowFetch(ok_d, fetches)
+
+    def frontier_mask(fstate, pairs_d, ok_d):
+        cpairs, n_ok = mask(fstate, _unwrap(pairs_d), _unwrap(ok_d))
+        return cpairs, _SlowFetch(n_ok, fetches)
+
+    def frontier_partition(cls_d):
+        order, n_emit, n_store = partition(cls_d)
+        return _SlowFetch(order, fetches), n_emit, n_store
+
+    p.frontier_dispatch = frontier_dispatch
+    p.frontier_mask = frontier_mask
+    p.frontier_partition = frontier_partition
+    return p
+
+
+def _slow_host_pipelines(fetches):
+    """Numpy level pipelines whose batch results are handed over slowly."""
+    from repro.core.placement import HostPlacement
+    from repro.kernels.intersect.ops import LevelPipeline
+
+    class SlowHandle:
+        def __init__(self, handle):
+            self.handle = handle
+
+        def result(self):
+            import time
+
+            fetches.append(1)
+            time.sleep(_DELAY_S)
+            return self.handle.result()
+
+    class SlowPipeline(LevelPipeline):
+        def submit(self, pairs, write_children):
+            return SlowHandle(super().submit(pairs, write_children))
+
+    placement = HostPlacement()
+    return lambda bits, counts, tau: SlowPipeline(
+        bits, counts, tau=tau, placement=placement, fused_classify=True
+    )
+
+
+@pytest.mark.parametrize("path", ["device", "device_bounds", "host"])
+def test_injected_device_delay_lands_under_device_wait(tracer_reset, path):
+    from repro.core import KyivConfig, mine, mine_preprocessed
+    from repro.core.kyiv import prepare
+
+    fetches = []
+    D = _rand(3, 300, 6)
+    kw = {}
+    if path == "host":
+        cfg = KyivConfig(tau=1, kmax=3, engine="numpy")
+        kw["pipeline_factory"] = _slow_host_pipelines(fetches)
+    else:
+        cfg = KyivConfig(tau=1, kmax=3, use_bounds=path == "device_bounds",
+                         placement=_slow_device_placement(fetches))
+    prep = prepare(D, cfg)
+    mine_preprocessed(prep, cfg, **kw)  # compiles: they would land in the stages
+    fetches.clear()
+    with TRACER.start("test.mine"):
+        result = mine_preprocessed(prep, cfg, **kw)
+    trace = TRACER.last(1)[0]
+    want = mine(D, KyivConfig(tau=1, kmax=3, engine="numpy"))
+    assert sorted(result.itemsets) == sorted(want.itemsets)
+
+    def total(name):
+        return sum(s.duration for s in trace.find(name))
+
+    assert len(fetches) >= 2
+    injected = _DELAY_S * len(fetches)
+    assert total("device.wait") >= injected
+    # the stage spans hold host work only: not one injected delay
+    assert total("level.classify") < _DELAY_S
+    assert total("frontier.candidates") < _DELAY_S
+    assert sum(ls.time_intersect for ls in result.stats) >= injected
+    assert sum(ls.time_classify + ls.time_candidates for ls in result.stats) < _DELAY_S
+    if path == "device_bounds":
+        assert {"ok", "pairs", "result"} <= {s.attrs["site"] for s in trace.find("device.wait")}
+    # every emitted batch is spanned, and emission is out of the level's self time
+    by_id = {s.span_id: s for s in trace.spans}
+    emits = trace.find("level.emit")
+    assert emits and all(by_id[s.parent_id].name == "mine.level" for s in emits)
+    _check_spans_against_stats(result, trace)
+
+
+def test_mine_reply_spans_decode_encode_write(obs_http_service):
+    import time
+
+    _, port = obs_http_service
+    t0 = time.perf_counter()
+    _, body = _req(port, "/mine", {"tau": 1, "kmax": 3})
+    client_s = time.perf_counter() - t0
+    trace = TRACER.get(json.loads(body)["trace_id"])
+    (respond,) = trace.find("http.respond")
+    kids = {s.name: s for s in trace.children_of(respond)}
+    assert set(kids) == {"http.decode", "http.encode", "http.write"}
+    assert all(s.duration > 0 for s in kids.values())
+    assert kids["http.decode"].t1 <= kids["http.encode"].t0 <= kids["http.write"].t0
+    assert sum(s.duration for s in kids.values()) <= min(respond.duration, client_s)
+
+
+def _host_event_names(xplane_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(xplane_dir, "**", "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
+
+
+def test_spans_lie_on_the_profiler_trace(tracer_reset, tmp_path):
+    import jax
+
+    from repro.launch.serve_miner import make_server
+    from repro.service import MiningService
+
+    dev = MiningService.from_dataset(_rand(0, 200, 4), engine="jnp")
+    server = make_server(dev, port=0)
+    thread = threading.Thread(target=server.serve_forever, daemon=True)
+    thread.start()
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            _req(server.server_address[1], "/mine", {"tau": 1, "kmax": 3})
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        server.shutdown()
+        server.server_close()
+        dev.close()
+    names = _host_event_names(str(tmp_path))
+    assert {"mine.level", "device.wait", "http.encode", "http /mine"} <= names, names
+
+
+def test_no_annotation_without_a_profiler_session(tracer_reset, monkeypatch):
+    import jax  # the annotation is only looked up once jax is loaded
+
+    from repro.obs import trace as obs_trace
+
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(obs_trace, "_ANNOTATION", Counting)
+    with TRACER.start("t"):
+        with obs_trace.span("a"):
+            pass
+    assert not Counting.is_enabled() and made == []
+
+
+def test_compile_is_a_span_and_counted_in_the_cost_envelope(tracer_reset):
+    from repro.service import MiningService
+
+    first, wider = _rand(5, 300, 6), _rand(6, 4000, 6)
+    svc = MiningService.from_dataset(first, engine="jnp")
+    twin = MiningService.from_dataset(np.concatenate([first, wider]), engine="jnp")
+    try:
+        svc.mine(tau=2, kmax=3)
+        # a wider table: the level loop's executables meet new shapes
+        svc.append(wider)
+        r = svc.mine(tau=2, kmax=3)
+        trace = TRACER.get(r.info["cost"]["trace_id"])
+        compiles = trace.find("jax.compile")
+        assert compiles and r.info["cost"]["executables_compiled"] == len(compiles)
+        assert all(s.t0 <= s.t1 and s.attrs["fun_name"] for s in compiles)
+        by_id = {s.span_id: s for s in trace.spans}
+
+        def in_level(sp):
+            while sp.parent_id is not None:
+                sp = by_id[sp.parent_id]
+                if sp.name == "mine.level":
+                    return True
+            return False
+
+        # a compile shows under the level that paid for it
+        assert any(in_level(s) for s in compiles)
+        # the same mine on the same shapes compiles nothing
+        r = twin.mine(tau=2, kmax=3)
+        assert r.info["cost"]["executables_compiled"] == 0
+        assert not TRACER.get(r.info["cost"]["trace_id"]).find("jax.compile")
+    finally:
+        svc.close()
+        twin.close()
