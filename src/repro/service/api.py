@@ -25,6 +25,8 @@ process-wide executable buckets (``kernels.intersect.ops.EXEC_CACHE``).
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import json
 import os
 import pickle
 import threading
@@ -63,6 +65,7 @@ from .wal import DurableStore
 __all__ = [
     "MineResponse",
     "MiningService",
+    "ReplyPieces",
     "NotReadyError",
     "DeadlineExceeded",
 ]
@@ -184,11 +187,8 @@ class MineResponse:
     def n_itemsets(self) -> int:
         return len(self.result.itemsets)
 
-    def to_json(self, max_itemsets: int | None = None) -> dict:
-        sets = self.result.as_value_sets()
-        truncated = max_itemsets is not None and len(sets) > max_itemsets
-        if truncated:
-            sets = sets[:max_itemsets]
+    def _head(self, truncated: bool) -> dict:
+        """The reply's keys before ``itemsets``, in wire order."""
         return {
             "version": self.version,
             "tau": self.tau,
@@ -198,12 +198,104 @@ class MineResponse:
             "latency_s": self.latency_s,
             "n_itemsets": self.n_itemsets,
             "truncated": truncated,
+        }
+
+    def to_json(self, max_itemsets: int | None = None) -> dict:
+        """The reply as a dict: the specification ``to_json_bytes`` meets."""
+        sets = self.result.as_value_sets()
+        truncated = max_itemsets is not None and len(sets) > max_itemsets
+        if truncated:
+            sets = sets[:max_itemsets]
+        return {
+            **self._head(truncated),
             "itemsets": [
                 {"items": [[int(c), int(v)] for c, v in ids], "count": int(cnt)}
                 for ids, cnt in sets
             ],
             "info": self.info,
         }
+
+    def reply_pieces(self, max_itemsets: int | None = None) -> "ReplyPieces":
+        """The itemsets resolved to byte pieces: the decode half of
+        ``to_json_bytes``."""
+        sets = self.result.itemsets
+        truncated = max_itemsets is not None and len(sets) > max_itemsets
+        if truncated:
+            sets = sets[:max_itemsets]
+        return ReplyPieces(self, truncated, _itemset_pieces(sets, self.result.prep.table))
+
+    def to_json_bytes(
+        self, max_itemsets: int | None = None, trace_id: str | None = None
+    ) -> bytes:
+        """``json.dumps(to_json(max_itemsets)).encode()``, with ``trace_id``
+        last when given, byte for byte, built from arrays."""
+        return self.reply_pieces(max_itemsets).to_bytes(trace_id)
+
+
+def _itemset_pieces(sets: list, table: ItemTable) -> list[np.ndarray]:
+    """One ``(n, k + 2)`` object array of byte pieces per maximal run of
+    equal-arity itemsets, in order: ``{"items": [``, the ``k`` item
+    fragments (``[col, value]``, comma-led after the first) and
+    ``], "count": c}, ``; the last itemset's close has no comma."""
+    n = len(sets)
+    if not n:
+        return []
+    ids, counts = zip(*sets)
+    lens = np.fromiter(map(len, ids), np.int64, n)
+    flat = np.fromiter(itertools.chain.from_iterable(ids), np.int64, int(lens.sum()))
+    count_values, count_of = np.unique(
+        np.fromiter(counts, np.int64, n), return_inverse=True
+    )
+    closes = np.array([b'], "count": %d}, ' % c for c in count_values.tolist()],
+                      dtype=object)
+    frags = [b"[%d, %d]" % (int(c), int(v))
+             for c, v in zip(table.col.tolist(), table.value.tolist())]
+    first = np.array(frags, dtype=object)
+    rest = np.array([b", " + f for f in frags], dtype=object)
+    bounds = [0, *(np.flatnonzero(np.diff(lens)) + 1).tolist(), n]
+    runs, offset = [], 0
+    for start, stop in zip(bounds[:-1], bounds[1:]):
+        k, m = int(lens[start]), stop - start
+        run_ids = flat[offset:offset + m * k].reshape(m, k)
+        offset += m * k
+        pieces = np.empty((m, k + 2), dtype=object)
+        pieces[:, 0] = b'{"items": ['
+        if k:
+            pieces[:, 1] = first[run_ids[:, 0]]
+            pieces[:, 2:k + 1] = rest[run_ids[:, 1:]]
+        pieces[:, k + 1] = closes[count_of[start:stop]]
+        runs.append(pieces)
+    runs[-1][-1, -1] = runs[-1][-1, -1][:-2]
+    return runs
+
+
+@dataclasses.dataclass
+class ReplyPieces:
+    """A ``/mine`` reply whose itemsets are resolved to byte pieces, one
+    object array per equal-arity run, awaiting the joins and the envelope."""
+
+    response: MineResponse
+    truncated: bool
+    runs: list[np.ndarray]
+
+    @property
+    def n_itemsets(self) -> int:
+        return sum(len(run) for run in self.runs)
+
+    def to_bytes(self, trace_id: str | None = None) -> bytes:
+        """The reply body: the envelope through ``json.dumps`` (default
+        separators), the itemsets spliced in at their key."""
+        head = json.dumps(self.response._head(self.truncated))
+        tail = {"info": self.response.info}
+        if trace_id:
+            tail["trace_id"] = trace_id
+        return b"".join((
+            head[:-1].encode(),
+            b', "itemsets": [',
+            *(b"".join(run.ravel().tolist()) for run in self.runs),
+            b"], ",
+            json.dumps(tail)[1:].encode(),
+        ))
 
 
 class MiningService:

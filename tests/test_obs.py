@@ -786,6 +786,18 @@ def test_injected_device_delay_lands_under_device_wait(tracer_reset, path):
     _check_spans_against_stats(result, trace)
 
 
+def _finished_trace(trace_id, timeout_s=10.0):
+    """The request's trace once its root span has closed: the client holds
+    the reply before the handler thread leaves the root span."""
+    import time
+
+    deadline = time.monotonic() + timeout_s
+    while (trace := TRACER.get(trace_id)) is None and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert trace is not None, trace_id
+    return trace
+
+
 def test_mine_reply_spans_decode_encode_write(obs_http_service):
     import time
 
@@ -793,13 +805,18 @@ def test_mine_reply_spans_decode_encode_write(obs_http_service):
     t0 = time.perf_counter()
     _, body = _req(port, "/mine", {"tau": 1, "kmax": 3})
     client_s = time.perf_counter() - t0
-    trace = TRACER.get(json.loads(body)["trace_id"])
+    trace = _finished_trace(json.loads(body)["trace_id"])
     (respond,) = trace.find("http.respond")
     kids = {s.name: s for s in trace.children_of(respond)}
     assert set(kids) == {"http.decode", "http.encode", "http.write"}
     assert all(s.duration > 0 for s in kids.values())
     assert kids["http.decode"].t1 <= kids["http.encode"].t0 <= kids["http.write"].t0
     assert sum(s.duration for s in kids.values()) <= min(respond.duration, client_s)
+    # the reply is built from arrays: what was encoded, in how many runs
+    reply = json.loads(body)
+    assert kids["http.decode"].attrs["itemsets"] == len(reply["itemsets"]) > 0
+    assert 1 <= kids["http.decode"].attrs["runs"] <= reply["kmax"]
+    assert kids["http.encode"].attrs["bytes"] == len(body)
 
 
 def _host_event_names(xplane_dir):
@@ -831,7 +848,8 @@ def test_spans_lie_on_the_profiler_trace(tracer_reset, tmp_path):
         options.python_tracer_level = 0
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
-            _req(server.server_address[1], "/mine", {"tau": 1, "kmax": 3})
+            _, body = _req(server.server_address[1], "/mine", {"tau": 1, "kmax": 3})
+            _finished_trace(json.loads(body)["trace_id"])  # the root span's annotation too
         finally:
             jax.profiler.stop_trace()
     finally:

@@ -5,6 +5,7 @@ tests/test_incremental.py (hypothesis); here are the deterministic
 subsystem contracts plus targeted incremental edge cases.
 """
 
+import dataclasses
 import json
 import threading
 import urllib.error
@@ -19,6 +20,7 @@ from repro.kernels.intersect import executable_cache_stats
 from repro.service import (
     DatasetStore,
     IncrementalConfig,
+    MineResponse,
     MiningService,
     RequestScheduler,
     ResultCache,
@@ -288,6 +290,74 @@ def test_service_warm_executables_across_requests():
 
 
 # ---------------------------------------------------------------------------
+# /mine reply bytes
+# ---------------------------------------------------------------------------
+
+
+def _spec_bytes(resp, max_itemsets, trace_id):
+    """What the HTTP layer sent before replies were built from arrays:
+    ``json.dumps`` of ``to_json`` with the trace id set as ``_encode`` sets it."""
+    doc = resp.to_json(max_itemsets)
+    if trace_id:
+        doc.setdefault("trace_id", trace_id)
+    return json.dumps(doc).encode()
+
+
+@pytest.fixture(scope="module")
+def reply_cases():
+    D = _rand(0, 200, 4, 5)
+    D[0, 0] = 9  # one rare value: a 1-itemset beside the 2- and 3-itemsets
+    svc = MiningService.from_dataset(D, engine="numpy")
+    real = svc.mine(tau=3, kmax=3)
+    partial = svc.mine(tau=1, kmax=4, deadline_s=0.0)
+    svc.close()
+    # column 4 relabels column 0: its items share their rowsets (mirrors)
+    M = _rand(1, 120, 3, 4)
+    svc = MiningService.from_dataset(np.concatenate([M, (M[:, :1] + 1) % 4], axis=1),
+                                     engine="numpy")
+    mirrored = svc.mine(tau=4, kmax=3)
+    svc.close()
+
+    res = real.result
+    by_k = {len(ids): (ids, c) for ids, c in res.itemsets}
+    # arities in non-monotone runs, with a duplicate kept in place
+    runs = [by_k[3], by_k[3], by_k[1], res.itemsets[-1], by_k[2]]
+    return {
+        "empty": (dataclasses.replace(real, result=dataclasses.replace(res, itemsets=[])), None),
+        "mine_k123": (real, None),
+        "runs_3_1_3_2": (dataclasses.replace(
+            real, result=dataclasses.replace(res, itemsets=runs)), None),
+        "max_0": (real, 0),
+        "max_5": (real, 5),
+        "max_over": (real, len(res.itemsets) + 1),
+        "mirrored": (mirrored, None),
+        "partial": (partial, None),
+        "nested_info": (dataclasses.replace(
+            real, latency_s=1 / 3,
+            info={"cost": {"wall_s": 0.1 + 0.2, "path": "cold"}, "ratio": 1e-9,
+                  "levels": [1.5, {"k": 2}], "name": "caf\u00e9"}), 2),
+    }
+
+
+@pytest.mark.parametrize("trace_id", [None, "a1b2c3d4e5f60718"])
+@pytest.mark.parametrize("case", ["empty", "mine_k123", "runs_3_1_3_2", "max_0", "max_5",
+                                  "max_over", "mirrored", "partial", "nested_info"])
+def test_mine_reply_bytes_equal_json_dumps_of_to_json(reply_cases, case, trace_id):
+    resp, max_itemsets = reply_cases[case]
+    assert isinstance(resp, MineResponse)
+    if case == "mine_k123":
+        assert {len(ids) for ids, _ in resp.result.itemsets} == {1, 2, 3}
+    if case == "mirrored":
+        assert resp.result.prep.mirror_of
+    if case == "partial":
+        assert resp.source == "partial" and resp.result.interrupted == "deadline"
+    got = resp.to_json_bytes(max_itemsets, trace_id)
+    assert got == _spec_bytes(resp, max_itemsets, trace_id)
+    pieces = resp.reply_pieces(max_itemsets)
+    assert pieces.n_itemsets == len(resp.to_json(max_itemsets)["itemsets"])
+
+
+# ---------------------------------------------------------------------------
 # HTTP endpoint
 # ---------------------------------------------------------------------------
 
@@ -347,6 +417,31 @@ def test_http_mine_append_report_cycle(http_service):
     code, stats = _req(port, "/stats")
     assert stats["store"]["n_rows"] == 215
     assert stats["cache"]["hits"] >= 1
+
+
+@pytest.mark.parametrize("max_itemsets", [None, 5])
+def test_http_mine_body_is_to_json_with_trace_id(http_service, monkeypatch, max_itemsets):
+    svc, port = http_service
+    served = []
+    mine_fn = svc.mine
+
+    def keep(**kw):
+        served.append(mine_fn(**kw))
+        return served[-1]
+
+    monkeypatch.setattr(svc, "mine", keep)
+    payload = {"tau": 1, "kmax": 3}
+    if max_itemsets is not None:
+        payload["max_itemsets"] = max_itemsets
+    resp = urllib.request.urlopen(urllib.request.Request(
+        f"http://127.0.0.1:{port}/mine", data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"}), timeout=30)
+    body = resp.read()
+    (mined,) = served
+    want = mined.to_json(max_itemsets)
+    want["trace_id"] = resp.headers["X-Trace-Id"]
+    assert want["itemsets"] and json.loads(body) == want
+    assert body == json.dumps(want).encode()
 
 
 def test_http_error_handling(http_service):
