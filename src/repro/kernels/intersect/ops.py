@@ -619,6 +619,17 @@ class LevelPipeline:
             None if outs[0] is None else cat(outs, axis=0) for outs in zip(*parts)
         )
 
+    def _padded_size(self, m: int) -> int:
+        """Rows a batch of ``m`` pairs is padded to: the placement's bucket
+        up to its ``max_dispatch_pairs``, whole chunks of that bound past it
+        (each chunk is one bucket, so the pad is under one chunk rather than
+        up to the next power of two)."""
+        size = self.placement.padded_size(m, pad_buckets=self.pad_buckets)
+        cap = getattr(self.placement, "max_dispatch_pairs", None)
+        if cap is not None and self.pad_buckets and size > cap:
+            return -(-m // cap) * cap
+        return size
+
     def _materializer(self, child_d, cnt_d, cls_d, m: int, inverse=None):
         n_words = self.n_words
 
@@ -656,7 +667,9 @@ class LevelPipeline:
         return BatchHandle(self._materializer(*raw, m), raw=raw)
 
     def submit(self, pairs: np.ndarray, write_children: bool) -> BatchHandle:
-        """Dispatch one batch of pair intersections; non-blocking on device placements."""
+        """Dispatch one batch of pair intersections; non-blocking on device
+        placements. ``raw()`` of the handle holds the padded outputs when the
+        pairs were dispatched in the order given (no locality sort)."""
         m = int(pairs.shape[0])
         if m == 0:
             W = self.n_words
@@ -674,9 +687,12 @@ class LevelPipeline:
             if order is not None:
                 pairs = pairs[order]
 
-        padded = _pad_pairs(pairs, self.placement.padded_size(m, pad_buckets=self.pad_buckets))
+        padded = _pad_pairs(pairs, self._padded_size(m))
         raw = self._dispatch(padded, write_children)
-        return BatchHandle(self._materializer(*raw, m, inverse))
+        # raw outputs are in submission order only when nothing was sorted
+        return BatchHandle(
+            self._materializer(*raw, m, inverse), raw=raw if order is None else None
+        )
 
 
 class LegacyIntersectPipeline:

@@ -829,8 +829,9 @@ class MiningService:
                             config,
                             self.incremental,
                             table=table,
-                            # seed expansion runs through this service's
-                            # placement, over the store's resident bitsets
+                            # seed expansion and the delta-born counts run
+                            # through this service's placement, over the
+                            # store's resident bitsets
                             # (None -> falls back to a host snapshot gather;
                             # bit-identical either way). Host placements skip
                             # the resident copy entirely.

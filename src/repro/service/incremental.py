@@ -9,6 +9,9 @@ current store version:
   is append-monotone. So every cached result only needs a **recount on the
   appended rows** (``DatasetStore.delta_bits``): new support = old support +
   delta support, at a cost proportional to the delta block, not the table.
+  On a device placement the recounted itemsets take their whole support
+  from the placement's intersect kernels over the current version's
+  resident bitsets instead, the kernels that count the delta-born pool.
 * A cached result whose support crossed τ is **promoted** to frequent. Any
   *new* minimal itemset ``S`` (one not in the base answer) was τ-infrequent
   in the base table too (monotonicity), hence contained a base-minimal
@@ -29,7 +32,9 @@ current store version:
   means their entire support lies in the delta block — every such itemset
   is a subset of some appended row's items, so ``_delta_born`` enumerates
   the ≤kmax column combinations of each appended row (cost per row is a
-  function of table *width*, not history) and classifies them directly.
+  function of table *width*, not history) and classifies them directly,
+  their full-table supports counted by the placement's intersect kernels
+  over the store's resident bitsets on a device placement.
 
 ``_expand_seeds`` explores exactly that band: a BFS over supersets of each
 seed within the frequent item universe, pruning any infrequent node (an
@@ -68,6 +73,8 @@ Two refinements ride on top of the base scheme:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import itertools
 import time
 
 import numpy as np
@@ -76,9 +83,18 @@ from ..core.bitops import popcount_rows
 from ..core.items import ItemTable
 from ..core.kyiv import KyivConfig, LevelStats, MiningResult
 from ..core.preprocess import Preprocessed
+from ..obs import metrics as _om
+from ..obs.trace import current_span as _obs_current_span
+from ..obs.trace import span as _obs_span
 from .store import DatasetStore, mask_delta_words, mask_delta_words_local
 
 __all__ = ["IncrementalConfig", "ResultBands", "mine_incremental", "delta_support"]
+
+_FALLBACKS = _om.counter(
+    "repro_incremental_fallbacks_total",
+    "Incremental mines handed back to a cold mine, by reason.",
+    ("reason",),
+)
 
 
 @dataclasses.dataclass
@@ -107,7 +123,7 @@ def _delta_bits_of(
     the full local width is kept and pre-existing rows are zeroed in place."""
     if word_map is not None:
         return mask_delta_words_local(table.bits, base_rows, word_map)
-    return mask_delta_words(table.bits, base_rows)[0]
+    return mask_delta_words(table.bits, base_rows, table.n_rows)[0]
 
 
 def delta_support(
@@ -174,6 +190,7 @@ class ResultBands:
         tau: int,
         d: int,
         reduce_fn=None,
+        supports=None,
     ) -> tuple[np.ndarray, dict]:
         """New (base + delta) support of every cached itemset, in cached
         order, touching bitsets only where the ``dfreq`` upper bound admits a
@@ -181,12 +198,16 @@ class ResultBands:
         frequency; under a fleet ``reduce_fn`` sums the partial popcounts
         (one collective for all arities — the upper-bound filter is computed
         from global values, so every process recounts the identical rows).
+        ``supports`` (``{k: (r, k) ids} -> {k: full-table supports}``, the
+        device counts on a device placement) replaces base + delta for the
+        recounted itemsets with their whole support on the current version.
         Returns ``(new_counts, stats)``."""
         total = sum(len(c) for c in self.counts.values())
         new = np.zeros(total, dtype=np.int64)
         n_recounted = 0
         n_band = 0
         chunks: list[tuple[int, np.ndarray, np.ndarray]] = []
+        full: dict[int, np.ndarray] = {}
         for k in sorted(self.mats):
             mat, cnt = self.mats[k], self.counts[k]
             if len(cnt) == 0:
@@ -201,9 +222,15 @@ class ResultBands:
             need = np.nonzero(ub > 0)[0]
             n_recounted += len(need)
             new[self.index[k]] = cnt  # ub == 0 rows are exact as-is
-            if len(need):
+            if len(need) and supports is not None:
+                full[k] = need
+            elif len(need):
                 inter = np.bitwise_and.reduce(dbits[mat[need]], axis=1)
                 chunks.append((k, need, popcount_rows(inter).astype(np.int64)))
+        if full:
+            counts = supports({k: self.mats[k][need] for k, need in full.items()})
+            for k, need in full.items():
+                new[self.index[k][need]] = counts[k]
         if chunks:
             flat = np.concatenate([c for _, _, c in chunks])
             if reduce_fn is not None:
@@ -220,41 +247,31 @@ class ResultBands:
         return new, stats
 
 
-def _itemset_support(bits: np.ndarray, ids: tuple[int, ...]) -> int:
-    inter = np.bitwise_and.reduce(bits[list(ids)], axis=0)
-    return int(popcount_rows(inter[None, :])[0])
-
-
-def _is_minimal(
-    bits: np.ndarray, freq: np.ndarray, ids: tuple[int, ...], tau: int
-) -> bool:
-    """All (|S|-1)-subsets frequent? (Sufficient: infrequency is superset-
-    monotone, so a deeper infrequent subset implies an infrequent
-    (|S|-1)-subset.)"""
-    if len(ids) == 1:
-        return True
-    if len(ids) == 2:
-        return bool(freq[ids[0]] > tau and freq[ids[1]] > tau)
-    for drop in range(len(ids)):
-        sub = ids[:drop] + ids[drop + 1 :]
-        if _itemset_support(bits, sub) <= tau:
-            return False
-    return True
+def _host_supports(bits: np.ndarray, cands: dict[int, np.ndarray]) -> dict[int, np.ndarray]:
+    """Support of each ``(r, k)`` id-matrix row: host ANDs over ``bits``."""
+    return {
+        k: popcount_rows(np.bitwise_and.reduce(bits[mat], axis=1))
+        for k, mat in cands.items()
+    }
 
 
 def _filter_minimal(
-    table: ItemTable, cands: dict[frozenset, int], tau: int, reduce_fn=None
+    table: ItemTable,
+    cands: dict[frozenset, int],
+    tau: int,
+    reduce_fn=None,
+    supports=None,
 ) -> dict[frozenset, int]:
     """Keep the minimal members of a τ-infrequent candidate pool, batched.
 
     Every distinct (|S|-1)-subset across all arity ≥ 3 candidates is counted
     once in one vectorised pass — under a fleet that is a single partial-
-    popcount all-reduce instead of one per leave-one-out probe (the
-    per-candidate ``_is_minimal`` would be a collective per subset).
+    popcount all-reduce instead of one per leave-one-out probe.
+    ``supports`` counts ``{k: (r, k) ids}`` (host ANDs over the table's
+    bitsets when omitted; the device counts on a device placement).
     Arity 1 is minimal by definition; arity 2 checks global frequencies.
     """
     freq = table.freq
-    bits = table.bits
     sub_index: dict[tuple[int, ...], int] = {}
     sub_list: list[tuple[int, ...]] = []
     refs_of: dict[frozenset, list[int]] = {}
@@ -277,12 +294,12 @@ def _filter_minimal(
         by_k: dict[int, list[int]] = {}
         for ix, sub in enumerate(sub_list):
             by_k.setdefault(len(sub), []).append(ix)
-        parts = []
-        for kk in sorted(by_k):
-            idxs = by_k[kk]
-            mat = np.asarray([sub_list[i] for i in idxs], dtype=np.int64)
-            inter = np.bitwise_and.reduce(bits[mat], axis=1)
-            parts.append((idxs, popcount_rows(inter).astype(np.int64)))
+        mats = {
+            kk: np.asarray([sub_list[i] for i in idxs], dtype=np.int64)
+            for kk, idxs in by_k.items()
+        }
+        counts = (supports or (lambda m: _host_supports(table.bits, m)))(mats)
+        parts = [(by_k[kk], counts[kk].astype(np.int64)) for kk in sorted(by_k)]
         flat = np.concatenate([p for _, p in parts])
         if reduce_fn is not None:
             flat = reduce_fn(flat)
@@ -331,7 +348,7 @@ def _expand_seeds(
     infrequent node is classified once (emit if minimal) and never extended,
     because its supersets all contain an infrequent proper subset.
     """
-    from ..kernels.intersect.ops import LevelPipeline
+    from ..kernels.intersect.ops import LevelPipeline, next_bucket
 
     n = table.n_rows
     freq = table.freq
@@ -348,6 +365,8 @@ def _expand_seeds(
             continue
         visited.add(fs)
         wave.append((fs, np.bitwise_and.reduce(bits[list(fs)], axis=0)))
+    if not wave:
+        return found
 
     if placement is None:
         from ..core.placement import HostPlacement
@@ -387,12 +406,16 @@ def _expand_seeds(
             if on_device:
                 import jax.numpy as jnp
 
+                # rows padded to a power of two: one executable per size
+                # class of waves, not one per wave
+                pad = next_bucket(f_count, 8) - f_count
+                wave_bits = np.pad(wave_bits, ((0, pad), (0, 0)))
                 mat = jnp.concatenate([ext_res, jnp.asarray(wave_bits)], axis=0)
             else:
                 mat = np.concatenate([ext_res, wave_bits], axis=0)
             pipe = LevelPipeline(
                 mat,
-                np.zeros(e_count + f_count, dtype=np.int64),
+                np.zeros(int(mat.shape[0]), dtype=np.int64),
                 tau=0,
                 placement=placement,
                 fused_classify=False,
@@ -407,7 +430,9 @@ def _expand_seeds(
                 )
                 ei = np.tile(np.arange(e_count, dtype=np.int64), c_count)
                 pairs = np.stack([fi, ei], axis=1).astype(np.int32)
-                _, counts, _ = pipe.submit(pairs, False).result()
+                handle = pipe.submit(pairs, False)
+                with _obs_span("device.wait", site="expand_counts"):
+                    _, counts, _ = handle.result()
                 counts = counts.reshape(c_count, e_count)
                 for fidx, (fs, fb) in enumerate(chunk):
                     # absent extensions (the overwhelming majority in sparse
@@ -431,7 +456,140 @@ def _expand_seeds(
                             found[cs] = cnt
             pipe.retire()
         wave = next_wave
-    return _filter_minimal(table, found, tau, reduce_fn)
+    supports = None
+    if on_device and reduce_fn is None:  # a fleet dispatch returns global counts
+        supports = functools.partial(
+            _device_supports, resident=resident_bits, placement=placement,
+            site="filter_counts",
+        )
+    return _filter_minimal(table, found, tau, reduce_fn, supports)
+
+
+def _unique_rows(
+    mat: np.ndarray, base: int, *, inverse: bool = False
+):
+    """Distinct rows of an ``(r, k)`` id matrix (ids < ``base``), in
+    lexicographic order; with ``inverse``, also each input row's index into
+    them. Rows are packed into one int64 key when ``base ** k`` fits."""
+    k = mat.shape[1]
+    if base**k >= 2**63:
+        res = np.unique(mat, axis=0, return_inverse=inverse)
+        return (res[0], res[1].reshape(-1)) if inverse else res
+    key = np.zeros(len(mat), dtype=np.int64)
+    for j in range(k):
+        key = key * base + mat[:, j]
+    res = np.unique(key, return_inverse=inverse)
+    key = res[0] if inverse else res
+    out = np.empty((len(key), k), dtype=np.int64)
+    for j in range(k - 1, -1, -1):
+        key, out[:, j] = np.divmod(key, base)
+    return (out, res[1].reshape(-1)) if inverse else out
+
+
+def _row_combinations(
+    row_items: np.ndarray, keep: np.ndarray, kmax: int, budget: int
+) -> tuple[dict[int, np.ndarray], bool]:
+    """Distinct 2..kmax-combinations of each row's kept items, by arity:
+    ``{k: (r, k) int64}``, each row ascending, rows in lexicographic order.
+
+    ``row_items`` is ``(n_items, rows)`` 0/1. Rows with the same number of
+    kept items form one ``(rows, m)`` item matrix whose column-index
+    combinations are gathered at once. The flag is True (and the pools are
+    partial) as soon as the distinct candidates, over all arities, exceed
+    ``budget``."""
+    base = len(keep)
+    kept = np.flatnonzero(keep)
+    sub = row_items[kept]
+    per_row = sub.sum(axis=0)
+    pools = {k: np.zeros((0, k), dtype=np.int64) for k in range(2, kmax + 1)}
+    for m in np.unique(per_row).tolist():
+        if m < 2:
+            continue
+        rows = np.flatnonzero(per_row == m)
+        _, pos = np.nonzero(sub[:, rows].T)  # row-major: each row's items, ascending
+        items = kept[pos].reshape(len(rows), m)
+        for k in range(2, min(kmax, m) + 1):
+            cols = np.asarray(list(itertools.combinations(range(m), k)), dtype=np.intp)
+            step = max(1, (1 << 22) // (len(cols) * k))  # bound the gather
+            for r0 in range(0, len(rows), step):
+                combos = items[r0 : r0 + step][:, cols].reshape(-1, k)
+                pools[k] = _unique_rows(np.concatenate([pools[k], combos]), base)
+                if sum(len(p) for p in pools.values()) > budget:
+                    return pools, True
+    return {k: p for k, p in pools.items() if len(p)}, False
+
+
+def _device_supports(
+    cands: dict[int, np.ndarray],
+    *,
+    resident,
+    placement,
+    site: str,
+    stats: dict | None = None,
+) -> dict[int, np.ndarray]:
+    """Full-table supports of candidate itemsets, counted by the placement's
+    intersect kernels over the store's resident bitsets.
+
+    A pair is one intersection of two item rows. An arity-k candidate is
+    the intersection of its (k-1)-prefix's child with its last item row; the
+    distinct prefixes are written as children first (recursively, so a
+    triple's prefixes are item pairs). Every batch is dispatched before the
+    single host fetch of the counts, a ``device.wait`` span named ``site``.
+    Returns the supports by arity; ``stats``, when given, gains the
+    intersections dispatched (``pairs``), the distinct rows each level's
+    dispatches read, summed (``rows_read``), and the rows written
+    (``children``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels.intersect.ops import LevelPipeline
+
+    if not cands:
+        return {}
+    n_items = int(resident.shape[0])
+    stats = {"pairs": 0, "rows_read": 0, "children": 0} if stats is None else stats
+    pipes: dict[int, LevelPipeline] = {}
+
+    def pipeline(mat):
+        if id(mat) not in pipes:
+            pipes[id(mat)] = LevelPipeline(
+                mat,
+                np.zeros(int(mat.shape[0]), dtype=np.int64),
+                tau=0,
+                placement=placement,
+                fused_classify=False,
+                locality_sort=False,
+            )
+        return pipes[id(mat)]
+
+    def operands(sets):
+        if sets.shape[1] == 2:
+            mat, left = resident, sets[:, 0]
+        else:
+            prefixes, inv = _unique_rows(sets[:, :-1], n_items, inverse=True)
+            child = write(prefixes)
+            if child.sharding != resident.sharding:  # a mesh shards pairs too
+                child = jax.device_put(child, resident.sharding)
+            items = resident.reshape((n_items,) + tuple(child.shape[1:]))
+            mat, left = jnp.concatenate([items, child]), n_items + inv
+        stats["rows_read"] += len(np.unique(np.concatenate([left, sets[:, -1]])))
+        stats["pairs"] += len(sets)
+        return pipeline(mat), np.stack([left, sets[:, -1]], axis=1).astype(np.int32)
+
+    def write(prefixes):
+        pipe, pairs = operands(prefixes)
+        stats["children"] += len(pairs)
+        return pipe.submit(pairs, True).raw()[0]
+
+    handles = {}
+    for k, sets in sorted(cands.items()):
+        pipe, pairs = operands(sets)
+        handles[k] = pipe.submit(pairs, False)
+    with _obs_span("device.wait", site=site):
+        out = {k: h.result()[1] for k, h in handles.items()}
+    for pipe in pipes.values():
+        pipe.retire()
+    return out
 
 
 def _delta_born(
@@ -444,7 +602,8 @@ def _delta_born(
     *,
     word_map: np.ndarray | None = None,
     coll=None,
-) -> dict[frozenset, int] | None:
+    device=None,
+) -> tuple[dict[frozenset, int], dict] | None:
     """Minimal τ-infrequent itemsets whose base support was 0.
 
     Their whole support lies in the appended rows, so every one is a subset
@@ -452,9 +611,13 @@ def _delta_born(
     from the item-major delta bitsets, each row's items are filtered to the
     frequent non-uniform universe (an infrequent or uniform member disquali-
     fies minimality immediately), and the surviving ≤kmax combinations are
-    counted vectorised against the full-width bitsets and checked for
-    minimality directly. Returns None when the deduplicated candidate pool
-    exceeds ``budget``.
+    counted against the full table and checked for minimality directly.
+    ``device`` (:func:`_device_supports` bound to a device placement and the
+    store's resident bitsets) counts the full-table supports and the
+    minimality probes on the device; without it they are host ANDs over the
+    full-width bitsets. Delta supports are host ANDs over
+    the few delta words either way. Returns ``(born, stats)``, or None when
+    the deduplicated candidate pool exceeds ``budget``.
 
     Under a fleet (``word_map`` + ``coll``) each process reconstructs only
     the delta rows living in its own word stripes, so the candidate pools
@@ -462,14 +625,13 @@ def _delta_born(
     *global* pool — either every process falls back to cold mining or none
     does. Support counts and the minimality filter reduce partial popcounts.
     """
-    import itertools
-
     n = table.n_rows
     freq = table.freq
     bits = table.bits
     d = n - base_rows
+    stats = {"candidates": {}, "pairs": 0, "rows_read": 0, "children": 0}
     if d <= 0 or kmax < 2:
-        return {}
+        return {}, stats
     # item-major delta bits -> per-row item lists (delta-scaled unpack)
     flat = np.unpackbits(
         np.ascontiguousarray(dbits).view(np.uint8), axis=1, bitorder="little"
@@ -485,67 +647,54 @@ def _delta_born(
         row_items = flat[:, (grow >= base_rows) & (grow < n)]
     keep = (freq > tau) & (freq < n)
 
-    cands: set[tuple[int, ...]] = set()
-    overflow = False
-    for r in range(row_items.shape[1]):
-        items = np.nonzero(row_items[:, r])[0]
-        items = items[keep[items]]
-        for k in range(2, min(kmax, len(items)) + 1):
-            for combo in itertools.combinations(items.tolist(), k):
-                cands.add(combo)
-                if len(cands) > budget:
-                    if coll is None:
-                        return None
-                    overflow = True  # verdict deferred to the global union
-                    break
-            if overflow:
-                break
-        if overflow:
-            break
+    cands, overflow = _row_combinations(row_items, keep, kmax, budget)
+    if overflow and coll is None:
+        return None
     if coll is not None:
-        pools = coll.allgather_obj((sorted(cands), overflow))
+        # verdict deferred to the global union
+        pools = coll.allgather_obj((cands, overflow))
         if any(o for _, o in pools):
             return None
-        union: set[tuple[int, ...]] = set()
-        for pool, _ in pools:
-            union.update(tuple(c) for c in pool)
-        if len(union) > budget:
+        cands = {
+            k: _unique_rows(np.concatenate(parts), len(keep))
+            for k in range(2, kmax + 1)
+            if (parts := [p[k] for p, _ in pools if k in p])
+        }
+        if sum(len(c) for c in cands.values()) > budget:
             return None
-        cands = union
+    stats["candidates"] = {k: len(c) for k, c in cands.items()}
 
     reduce_fn = coll.allreduce_sum if coll is not None else None
-    pre: dict[frozenset, int] = {}
-    by_k: dict[int, list[tuple[int, ...]]] = {}
-    for c in sorted(cands):
-        by_k.setdefault(len(c), []).append(c)
+    supports = None
+    if device is not None:
+        supports = functools.partial(device, site="filter_counts")
+        counts = device(cands, site="born_counts", stats=stats)
+    else:
+        counts = _host_supports(bits, cands)
     parts = []
-    for k in sorted(by_k):
-        sets_k = by_k[k]
-        mat = np.asarray(sets_k, dtype=np.int64)  # (r, k)
-        counts = popcount_rows(np.bitwise_and.reduce(bits[mat], axis=1))
+    for k, mat in sorted(cands.items()):
         dcounts = popcount_rows(np.bitwise_and.reduce(dbits[mat], axis=1))
-        parts.append((sets_k, counts.astype(np.int64), dcounts.astype(np.int64)))
+        parts.append((mat, counts[k].astype(np.int64), dcounts.astype(np.int64)))
     if parts and reduce_fn is not None:
         # one collective for all arities: [counts | dcounts] concatenated
-        flat_counts = np.concatenate(
-            [np.concatenate([c, dc]) for _, c, dc in parts]
+        flat_counts = reduce_fn(
+            np.concatenate([np.concatenate([c, dc]) for _, c, dc in parts])
         )
-        flat_counts = reduce_fn(flat_counts)
         off = 0
         fixed = []
-        for sets_k, c, dc in parts:
-            r = len(sets_k)
-            fixed.append((sets_k, flat_counts[off : off + r], flat_counts[off + r : off + 2 * r]))
+        for mat, c, dc in parts:
+            r = len(mat)
+            fixed.append((mat, flat_counts[off : off + r], flat_counts[off + r : off + 2 * r]))
             off += 2 * r
         parts = fixed
-    for sets_k, counts, dcounts in parts:
-        for ids, cnt, dcnt in zip(sets_k, counts, dcounts):
-            cnt = int(cnt)
-            # cnt == dcnt <=> base support 0: itemsets present at the base are
-            # exactly the family already covered by recount + seed expansion
-            if 1 <= cnt <= tau and cnt == int(dcnt):
-                pre[frozenset(ids)] = cnt
-    return _filter_minimal(table, pre, tau, reduce_fn)
+    pre: dict[frozenset, int] = {}
+    for mat, counts_k, dcounts in parts:
+        # cnt == dcnt <=> base support 0: itemsets present at the base are
+        # exactly the family already covered by recount + seed expansion
+        sel = np.flatnonzero((counts_k >= 1) & (counts_k <= tau) & (counts_k == dcounts))
+        for ids, cnt in zip(mat[sel].tolist(), counts_k[sel].tolist()):
+            pre[frozenset(ids)] = int(cnt)
+    return _filter_minimal(table, pre, tau, reduce_fn, supports), stats
 
 
 def _light_prep(table: ItemTable, tau: int) -> Preprocessed:
@@ -572,6 +721,12 @@ def _light_prep(table: ItemTable, tau: int) -> Preprocessed:
     )
 
 
+def _fallback(reason: str) -> None:
+    _FALLBACKS.inc(reason=reason)
+    _obs_current_span().set(fallback=reason)
+    return None
+
+
 def mine_incremental(
     store: DatasetStore,
     base_result: MiningResult,
@@ -590,36 +745,38 @@ def mine_incremental(
     to mine; when omitted one is taken now. Only the historical watermarks of
     ``store`` are consulted otherwise, so concurrent appends cannot skew the
     delta. ``placement``/``resident_bits`` route the promoted/new-item seed
-    expansion through the service's placement and the store's
-    device-resident bitsets (``DatasetStore.device_bits``) instead of
-    rebuilding host levels; omitted, the expansion runs on host numpy —
-    results are bit-identical either way. ``bands`` is the cached
+    expansion and the delta-born full-table counts through the service's
+    placement and the store's device-resident bitsets
+    (``DatasetStore.device_bits``) instead of host numpy; omitted, both run
+    on the host — results are bit-identical either way. ``bands`` is the cached
     :class:`ResultBands` companion of ``base_result`` (built on the fly when
     absent, so callers without a cache still get the shrunken recount). A
     ``FleetPlacement`` switches every stage into its collective form (see
     module docstring). Returns ``(result, info)`` or ``None`` when the
     caller should fall back to a cold mine (delta too large, expansion
-    budget exhausted, or a config the incremental invariants don't cover).
+    budget exhausted, or a config the incremental invariants don't cover);
+    each fallback counts in ``repro_incremental_fallbacks_total`` under its
+    ``reason``, which also lands on the open span (``mine.incremental``).
     """
     inc = inc_config or IncrementalConfig()
     if not inc.enabled or config.expansion != "full" or config.kmax < 1:
-        return None
+        return _fallback("config")
     try:
         base_rows = store.rows_at(base_version)
         base_items = store.items_at(base_version)
     except KeyError:
-        return None  # base watermark compacted away -> cold remine
+        return _fallback("compacted")  # base watermark gone -> cold remine
     if base_rows == 0:
-        return None
+        return _fallback("empty_base")
     t0 = time.perf_counter()
     if table is None:
         table = store.item_table()
     n = table.n_rows
     delta_rows = n - base_rows
     if delta_rows <= 0:
-        return None
+        return _fallback("no_delta")
     if delta_rows > inc.max_delta_fraction * n:
-        return None
+        return _fallback("delta_fraction")
 
     tau, kmax = config.tau, config.kmax
 
@@ -633,19 +790,32 @@ def mine_incremental(
     reduce_fn = coll.allreduce_sum if fleet else None
     shard = tuple(getattr(store, "shard", (0, 1)))
     word_map = store.word_map(table.n_words) if shard[1] > 1 else None
+    device = None
+    if not fleet and resident_bits is not None and getattr(placement, "kind", "host") != "host":
+        device = functools.partial(
+            _device_supports, resident=resident_bits, placement=placement
+        )
 
     # 1. recount cached results on the appended rows — only where the
-    # per-item delta-frequency bound admits a non-zero delta support
-    dbits = _delta_bits_of(table, base_rows, word_map)
-    dfreq = popcount_rows(dbits).astype(np.int64)
-    if reduce_fn is not None:
-        dfreq = reduce_fn(dfreq)
-    if bands is None:
-        bands = ResultBands.from_result(base_result.itemsets)
-    old_sets = [ids for ids, _ in base_result.itemsets]
-    new_counts, band_stats = bands.recount(
-        dbits, dfreq, tau, delta_rows, reduce_fn
-    )
+    # per-item delta-frequency bound admits a non-zero delta support. On a
+    # device placement those itemsets' whole supports are the device's
+    # counts over the current version's resident bitsets
+    recount_stats = {"pairs": 0, "rows_read": 0, "children": 0}
+    with _obs_span("incremental.recount") as sp:
+        dbits = _delta_bits_of(table, base_rows, word_map)
+        dfreq = popcount_rows(dbits).astype(np.int64)
+        if reduce_fn is not None:
+            dfreq = reduce_fn(dfreq)
+        if bands is None:
+            bands = ResultBands.from_result(base_result.itemsets)
+        old_sets = [ids for ids, _ in base_result.itemsets]
+        supports = None
+        if device is not None:
+            supports = functools.partial(device, site="recount_counts", stats=recount_stats)
+        new_counts, band_stats = bands.recount(
+            dbits, dfreq, tau, delta_rows, reduce_fn, supports
+        )
+        sp.set(**band_stats, pairs_counted=recount_stats["pairs"])
 
     results: list[tuple[tuple[int, ...], int]] = []
     seeds: list[tuple[int, ...]] = []
@@ -667,33 +837,44 @@ def mine_incremental(
 
     # 3. boundary expansion: previously-present new minimal itemsets are
     # strict supersets of a seed, explored through the resident frontier
-    expanded = _expand_seeds(
-        table,
-        seeds,
-        tau,
-        kmax,
-        inc.expansion_budget,
-        placement=placement,
-        resident_bits=resident_bits,
-        reduce_fn=reduce_fn,
-    )
+    with _obs_span("incremental.expand", seeds=len(seeds)) as sp:
+        expanded = _expand_seeds(
+            table,
+            seeds,
+            tau,
+            kmax,
+            inc.expansion_budget,
+            placement=placement,
+            resident_bits=resident_bits,
+            reduce_fn=reduce_fn,
+        )
+        sp.set(expanded=None if expanded is None else len(expanded))
     if expanded is None:
-        return None
+        return _fallback("budget_expansion")
 
     # 4. delta-born itemsets: absent at the base (support 0 is never cached),
     # supported entirely inside the appended block
-    born = _delta_born(
-        table,
-        dbits,
-        base_rows,
-        tau,
-        kmax,
-        inc.delta_candidate_budget,
-        word_map=word_map,
-        coll=coll,
-    )
-    if born is None:
-        return None
+    with _obs_span("incremental.delta_born") as sp:
+        out = _delta_born(
+            table,
+            dbits,
+            base_rows,
+            tau,
+            kmax,
+            inc.delta_candidate_budget,
+            word_map=word_map,
+            coll=coll,
+            device=device,
+        )
+        if out is not None:
+            born, born_stats = out
+            sp.set(
+                candidates=born_stats["candidates"],
+                pairs_counted=born_stats["pairs"],
+                born=len(born),
+            )
+    if out is None:
+        return _fallback("budget_candidates")
     n_expanded = len(expanded)
     expanded.update(born)
 
@@ -729,6 +910,17 @@ def mine_incremental(
         "n_delta_born": len(born),
         "n_cached": len(old_sets),
         **band_stats,
+        # the delta-born counts' device work, from which a reader reckons
+        # the bytes they move: rows read and children written per dispatch
+        "born_candidates": {str(k): v for k, v in born_stats["candidates"].items()},
+        "born_pairs_counted": born_stats["pairs"],
+        "born_rows_read": born_stats["rows_read"],
+        "born_children_written": born_stats["children"],
+        # and the recount's, when its supports are the device's counts
+        "recount_pairs_counted": recount_stats["pairs"],
+        "recount_rows_read": recount_stats["rows_read"],
+        "recount_children_written": recount_stats["children"],
+        "device_words": int(resident_bits.shape[-1]) if device is not None else 0,
     }
     if fleet:
         info["fleet"] = coll.stats()

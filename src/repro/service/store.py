@@ -9,8 +9,10 @@ the intersection kernels consume — **live across requests**:
 * Item bitsets are stored in the kernels' word-tile layout: the word
   dimension is padded to a multiple of ``word_tile`` so that the padded width
   (and hence the Pallas BlockSpec tiling and the executable buckets in
-  ``kernels.intersect.ops.EXEC_CACHE``) stays stable while rows accumulate
-  inside a tile, and only steps tile-by-tile afterwards. When the store is
+  ``kernels.intersect.ops.EXEC_CACHE``) stays stable while rows accumulate.
+  The first load is snug; an append that overflows the width grows it to
+  hold 9/8 of the rows, so a stream of appends changes the device shapes
+  once per eighth of growth, not once per tile. When the store is
   built for a ``repro.core.placement.BitsetPlacement``, the tile is aligned
   to the placement's ``store_word_tile`` (the word-shard count on a mesh), so
   append blocks itemize **directly into per-shard word tiles** — placing the
@@ -68,6 +70,7 @@ import threading
 import numpy as np
 
 from ..core.items import WORD_BITS, ItemTable
+from ..obs.trace import span as _obs_span
 
 __all__ = ["DatasetStore", "mask_delta_words", "mask_delta_words_local"]
 
@@ -75,15 +78,19 @@ _MIN_ITEM_CAP = 64
 _MIN_WORD_CAP = 8
 
 
-def mask_delta_words(bits: np.ndarray, base_rows: int) -> tuple[np.ndarray, int]:
-    """Slice a (t, W) bitset matrix down to the words covering rows >=
-    ``base_rows``, masking off the straddling word's pre-existing bits.
+def mask_delta_words(
+    bits: np.ndarray, base_rows: int, n_rows: int
+) -> tuple[np.ndarray, int]:
+    """Slice a (t, W) bitset matrix down to the words covering rows in
+    ``[base_rows, n_rows)`` (the pad words a grown store keeps past its last
+    row are left out), masking off the straddling word's pre-existing bits.
 
     Returns ``(delta bits (t, W_delta) uint32, word_lo)``; popcounts over the
     result are exact delta supports. Shared by :meth:`DatasetStore.delta_bits`
     (live store) and the incremental miner (immutable snapshots)."""
     word_lo = base_rows // WORD_BITS
-    sub = bits[:, word_lo:].copy()
+    word_hi = -(-int(n_rows) // WORD_BITS)
+    sub = bits[:, word_lo:word_hi].copy()
     keep = base_rows % WORD_BITS
     if keep:
         sub[:, 0] &= np.uint32(0xFFFFFFFF) << np.uint32(keep)
@@ -283,7 +290,7 @@ class DatasetStore:
         d = rows.shape[0]
         if d == 0:
             return self.version
-        with self._lock:
+        with self._lock, _obs_span("store.append", rows=int(d)) as sp:
             base = self.n_rows
             total = base + d
             pid, nproc = self.shard
@@ -291,8 +298,16 @@ class DatasetStore:
             # round-robin stripes divide exactly: every process's local
             # width is identical, keeping lockstep batch sizing in sync
             unit = self.word_tile * nproc
-            words_exact = (total + WORD_BITS - 1) // WORD_BITS
-            n_words_global = ((words_exact + unit - 1) // unit) * unit
+            n_words_global = self._n_words_global
+            if total > n_words_global * WORD_BITS:
+                # the first load is snug; an append that overflows grows the
+                # width to hold 9/8 of the rows, so a feed of appends changes
+                # the device shapes (and compiles) once per eighth of growth
+                # rather than once per word tile
+                rows_cap = total if base == 0 else -(-9 * total // 8)
+                words = -(-rows_cap // WORD_BITS)
+                n_words_global = -(-words // unit) * unit
+            grew = n_words_global > self._n_words_global
             n_words = n_words_global // nproc
 
             global_rows = base + np.arange(d, dtype=np.int64)
@@ -338,6 +353,7 @@ class DatasetStore:
 
             self._n_words = max(self._n_words, n_words)
             self._n_words_global = max(self._n_words_global, n_words_global)
+            sp.set(words=self._n_words, grew=grew)
             self.n_rows = total
             self.version += 1
             self._watermarks[self.version] = (self.n_rows, self._n_items)
@@ -567,7 +583,7 @@ class DatasetStore:
                 # keep the full local width (word_lo = 0) and zero the
                 # pre-existing rows' words instead of slicing them off
                 return mask_delta_words_local(view, base_rows, self.word_map()), 0
-            return mask_delta_words(view, base_rows)
+            return mask_delta_words(view, base_rows, self.n_rows)
 
     def device_bits(self, version: int | None = None):
         """Full bitset matrix placed for the store's placement, once per
@@ -586,11 +602,12 @@ class DatasetStore:
             cached = self._device.get(self.version)
             if cached is None:
                 view = self._bits[: self._n_items, : self._n_words]
-                if self.placement is not None:
-                    cached = self.placement.put_bits(view)
-                else:
-                    import jax.numpy as jnp
+                with _obs_span("store.device_put", bytes=int(view.nbytes)):
+                    if self.placement is not None:
+                        cached = self.placement.put_bits(view)
+                    else:
+                        import jax.numpy as jnp
 
-                    cached = jnp.asarray(view)
+                        cached = jnp.asarray(view)
                 self._device[self.version] = cached
             return cached

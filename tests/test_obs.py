@@ -884,7 +884,9 @@ def test_compile_is_a_span_and_counted_in_the_cost_envelope(tracer_reset):
 
     first, wider = _rand(5, 300, 6), _rand(6, 4000, 6)
     svc = MiningService.from_dataset(first, engine="jnp")
-    twin = MiningService.from_dataset(np.concatenate([first, wider]), engine="jnp")
+    # fed as svc is, so that its store grows to svc's width
+    twin = MiningService.from_dataset(first, engine="jnp")
+    twin.append(wider)
     try:
         svc.mine(tau=2, kmax=3)
         # a wider table: the level loop's executables meet new shapes

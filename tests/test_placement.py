@@ -132,7 +132,7 @@ def test_pallas_placement_lane_pads_and_bounds_dispatch():
     pc = np.bitwise_count(bits).sum(1)
     pairs = RNG.integers(0, 6, size=(300, 2)).astype(np.int32)
     want_child = bits[pairs[:, 0]] & bits[pairs[:, 1]]
-    p.max_dispatch_pairs = 128  # 300 pairs pad to 512 -> four dispatches
+    p.max_dispatch_pairs = 128  # 300 pairs pad to 384 -> three dispatches
     pipe = LevelPipeline(bits, pc, tau=40, placement=p)
     assert pipe._state[0].shape == (6, 1, 128)  # lane-padded row layout
     child, counts, classes = pipe.submit(pairs, True).result()
@@ -140,6 +140,35 @@ def test_pallas_placement_lane_pads_and_bounds_dispatch():
     assert np.array_equal(counts, np.bitwise_count(want_child).sum(1))
     host = LevelPipeline(bits, pc, tau=40, placement=HostPlacement())
     assert np.array_equal(classes, host.submit(pairs, True).result()[2])
+
+
+@pytest.mark.parametrize("m,sizes", [(100, [128]), (128, [128]), (129, [128, 128]),
+                                     (300, [128, 128, 128])])
+def test_submit_pads_past_the_dispatch_bound_to_whole_chunks(m, sizes):
+    """Up to the placement's dispatch bound a batch pads to its bucket; past
+    it, to whole chunks of the bound (one bucket each), not to the next
+    power of two. The raw outputs of an unsorted batch are in its order."""
+    p = DevicePlacement("pallas")
+    p.max_dispatch_pairs = 128
+    seen = []
+    dispatch = p.dispatch
+
+    def recording(state, padded, write_children):
+        seen.append(int(padded.shape[0]))
+        return dispatch(state, padded, write_children)
+
+    p.dispatch = recording
+    bits = RNG.integers(0, 2**32, size=(6, 5), dtype=np.uint32)
+    pairs = RNG.integers(0, 6, size=(m, 2)).astype(np.int32)
+    pipe = LevelPipeline(bits, np.bitwise_count(bits).sum(1), tau=0, placement=p,
+                         fused_classify=False, locality_sort=False)
+    handle = pipe.submit(pairs, True)
+    assert seen == sizes
+    want = bits[pairs[:, 0]] & bits[pairs[:, 1]]
+    assert np.array_equal(handle.result()[1], np.bitwise_count(want).sum(1))
+    child = np.asarray(handle.raw()[0])
+    assert child.shape[0] == sum(sizes)
+    assert np.array_equal(child.reshape(child.shape[0], -1)[:m, :5], want)
 
 
 # ---------------------------------------------------------------------------
