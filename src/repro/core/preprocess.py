@@ -13,7 +13,7 @@ Steps, exactly as the paper prescribes:
   4. ``L`` is sorted ascending (Def. 4.5): by ``(|R_a|, j_a, min R_a)``.
 
 Duplicate row-set detection hashes bitset rows (exact: hash, then verify
-within hash buckets) — O(items × W).
+within hash buckets) — O(items × W), in blocks of items × words.
 """
 
 from __future__ import annotations
@@ -36,6 +36,11 @@ ORDERINGS = ("ascending", "descending", "random")
 # collective rounds per preprocess, after which every process holds the
 # identical canonical/mirror partition.
 _ROW_GROUP_COLLECTIVE = None
+
+# Block of the row hash: the rows are read this many items by this many
+# words at a time (4 MiB of uint32), so hashing a wide store never doubles it.
+_HASH_BLOCK_ITEMS = 256
+_HASH_BLOCK_WORDS = 4096
 
 
 def set_row_group_collective(coll):
@@ -60,6 +65,8 @@ class Preprocessed:
       mirror_of: dict canonical item id -> list of duplicate item ids (``L̄``).
       l_bits: (|L|, W) uint32 bitsets of ``L`` rows, ordered like ``l_items``.
       l_freq: (|L|,) frequencies, same order.
+      verified_buckets: hash buckets of more than one item that went through
+        the exact row check of the duplicate-row-set detection.
     """
 
     table: ItemTable
@@ -70,27 +77,56 @@ class Preprocessed:
     mirror_of: dict[int, list[int]]
     l_bits: np.ndarray
     l_freq: np.ndarray
+    verified_buckets: int = 0
 
     @property
     def n_l(self) -> int:
         return int(self.l_items.shape[0])
 
 
-def _row_set_groups(table: ItemTable, ids: np.ndarray) -> list[np.ndarray]:
+def _hash_coefficients(w0: int, w1: int) -> np.ndarray:
+    """Fixed odd 64-bit multipliers of words ``w0 .. w1 - 1`` of a row: the
+    splitmix64 finaliser of the word index, so every word position has its
+    own multiplier and no block of the row can trade places with another."""
+    x = np.arange(w0, w1, dtype=np.uint64) + np.uint64(0x9E3779B97F4A7C15)
+    x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return (x ^ (x >> np.uint64(31))) | np.uint64(1)
+
+
+def _row_hashes(bits: np.ndarray, ids: np.ndarray) -> np.ndarray:
+    """Multilinear hash of the bitset rows ``bits[ids]``: the sum over words
+    of word × ``_hash_coefficients``, mod 2**64.
+
+    Equal rows hash equal, and the multiplier depends only on the word's
+    position in ``bits``, so under the fleet collective equal stripes hash
+    equal on every process. The rows are read straight from ``bits`` in
+    ``(_HASH_BLOCK_ITEMS, _HASH_BLOCK_WORDS)`` blocks: no ``(g, W)`` copy or
+    uint64 temporary of the whole selection is made, whatever the width.
+    """
+    h = np.zeros(len(ids), dtype=np.uint64)
+    n_words = bits.shape[1]
+    for w0 in range(0, n_words, _HASH_BLOCK_WORDS):
+        w1 = min(w0 + _HASH_BLOCK_WORDS, n_words)
+        coef = _hash_coefficients(w0, w1)
+        for i0 in range(0, len(ids), _HASH_BLOCK_ITEMS):
+            i1 = i0 + _HASH_BLOCK_ITEMS
+            h[i0:i1] += np.dot(bits[ids[i0:i1], w0:w1], coef)
+    return h
+
+
+def _row_set_groups(table: ItemTable, ids: np.ndarray) -> tuple[list[np.ndarray], int]:
     """Group item ids by identical row sets (bitset rows). Exact.
 
-    Returns a list of arrays; each array holds the ids sharing one row set,
-    in ascending item-id order.
+    Returns ``(groups, verified_buckets)``: a list of arrays, each holding
+    the ids sharing one row set in ascending item-id order, the groups in
+    ascending order of their first id; and the number of hash buckets of
+    more than one item that went through the exact row check.
     """
     if len(ids) == 0:
-        return []
-    sub = table.bits[ids]  # (g, W)
+        return [], 0
     # Hash each row, then verify within buckets to keep exactness.
-    mix = np.uint64(0x9E3779B97F4A7C15)
-    h = np.zeros(len(ids), dtype=np.uint64)
-    for w in range(sub.shape[1]):
-        h = (h ^ sub[:, w].astype(np.uint64)) * mix
-        h ^= h >> np.uint64(29)
+    h = _row_hashes(table.bits, ids)
     coll = _ROW_GROUP_COLLECTIVE
     if coll is not None:
         # round 1: fold every process's local hashes (pid order is fixed by
@@ -104,16 +140,9 @@ def _row_set_groups(table: ItemTable, ids: np.ndarray) -> list[np.ndarray]:
             combined ^= combined >> np.uint64(31)
         h = combined
     order = np.argsort(h, kind="stable")
-    ordered = ids[order]
     hs = h[order]
-    buckets: list[np.ndarray] = []
-    i = 0
-    while i < len(ordered):
-        j = i + 1
-        while j < len(ordered) and hs[j] == hs[i]:
-            j += 1
-        buckets.append(ordered[i:j])
-        i = j
+    starts = np.flatnonzero(hs[1:] != hs[:-1]) + 1
+    buckets = np.split(ids[order], starts)
     # exact verification within each multi-element bucket: all pairwise
     # equality flags in one flat vector. Locally that is just array_equal;
     # under a collective the flags AND-reduce (round 2: sum == nproc) so a
@@ -155,7 +184,8 @@ def _row_set_groups(table: ItemTable, ids: np.ndarray) -> list[np.ndarray]:
             same = [r for r in rem if eq[head, r]]
             groups.append(np.asarray(sorted(int(bucket[r]) for r in same), dtype=np.int64))
             rem = [r for r in rem if r not in same]
-    return groups
+    groups.sort(key=lambda g: int(g[0]))
+    return groups, len(multis)
 
 
 def preprocess(
@@ -178,7 +208,7 @@ def preprocess(
     keep_mask = (freq > tau) & (freq < n)
     remaining = np.nonzero(keep_mask)[0]
 
-    groups = _row_set_groups(table, remaining)
+    groups, verified_buckets = _row_set_groups(table, remaining)
     canonical = np.asarray([int(g[0]) for g in groups], dtype=np.int64)
     mirror_of = {int(g[0]): [int(x) for x in g[1:]] for g in groups if len(g) > 1}
 
@@ -204,4 +234,5 @@ def preprocess(
         mirror_of=mirror_of,
         l_bits=np.ascontiguousarray(table.bits[l_items]),
         l_freq=table.freq[l_items].astype(np.int64),
+        verified_buckets=verified_buckets,
     )

@@ -594,9 +594,15 @@ class MiningService:
                 self._preps.move_to_end(key)
                 return prep
         t0 = time.perf_counter()
-        with _obs_span("mine.preprocess", version=version, tau=config.tau):
+        with _obs_span("mine.preprocess", version=version, tau=config.tau) as sp:
             prep = preprocess(
                 table, config.tau, ordering=config.ordering, seed=config.seed
+            )
+            # items hashed for duplicate row sets: the canonical and mirror items
+            sp.set(
+                items=prep.n_l + sum(len(m) for m in prep.mirror_of.values()),
+                words=int(table.bits.shape[1]),
+                verified_buckets=prep.verified_buckets,
             )
         _PREPROCESS_SECONDS.observe(time.perf_counter() - t0)
         with self._lock:

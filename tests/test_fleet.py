@@ -21,6 +21,7 @@ import socket
 import subprocess
 import sys
 import threading
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -310,6 +311,42 @@ def test_fleet_mining_bit_identical(cfg):
         assert [s.emitted for s in out.stats] == [
             s.emitted for s in baseline.stats
         ]
+
+
+@pytest.mark.parametrize("forced_collision", [False, True])
+def test_fleet_row_groups_need_every_stripe(monkeypatch, forced_collision):
+    """Rows equal on one process's word stripes but not on the other's stay
+    apart, and the fleet's partition is the single-process one."""
+    if forced_collision:  # every row in one bucket: the AND-reduce decides
+        monkeypatch.setattr(
+            _pre, "_row_hashes",
+            lambda bits, ids: np.zeros(len(ids), dtype=np.uint64),
+        )
+    rng = np.random.default_rng(4)
+    tile, n_tiles = 4, 6
+    bits = rng.integers(0, 2**32, size=(8, tile * n_tiles), dtype=np.uint32)
+    stripes = [
+        np.concatenate([np.arange(t * tile, (t + 1) * tile)
+                        for t in range(p, n_tiles, NPROC)])
+        for p in range(NPROC)
+    ]
+    bits[1] = bits[0]
+    bits[1, stripes[1][-1]] ^= 1  # 0 and 1 agree on process 0's stripes only
+    bits[3, stripes[1]] = bits[2, stripes[1]]  # 2 and 3 on process 1's only
+    bits[5] = bits[4]  # 4 and 5 everywhere
+    bits[7] = bits[4]
+    ids = np.arange(8, dtype=np.int64)
+    single, _ = _pre._row_set_groups(SimpleNamespace(bits=bits), ids)
+    assert [g.tolist() for g in single] == [[0], [1], [2], [3], [4, 5, 7], [6]]
+
+    def worker(p, tc):
+        local = SimpleNamespace(bits=np.ascontiguousarray(bits[:, stripes[p]]))
+        groups, verified = _pre._row_set_groups(local, ids)
+        return [g.tolist() for g in groups], verified
+
+    for groups, verified in _run_fleet(worker):
+        assert groups == [g.tolist() for g in single]
+        assert verified == 1
 
 
 def test_fleet_incremental_bit_identical():
