@@ -22,7 +22,7 @@ from repro.core import KyivConfig, itemize, preprocess
 from repro.core.kyiv import mine_preprocessed
 from repro.core.sharded import make_sharded_pipeline
 from repro.data.synth import randomized_dataset
-from repro.distributed.checkpoint import CheckpointManager
+from repro.service.checkpoint import CheckpointManager
 
 
 def main() -> None:

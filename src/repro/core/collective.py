@@ -71,7 +71,7 @@ class Collective:
     pid: int = 0
     nproc: int = 1
 
-    # cumulative accounting (the bench multi-host row and /stats read these)
+    # cumulative accounting (the fleet's /stats reads these)
     rounds: int = 0
     seconds: float = 0.0
     payload_bytes: int = 0

@@ -450,9 +450,7 @@ def _advance_host(
                 store = classes == CLASS_STORE
                 n_skipped = len(classes) - len(inf_rows) - int(store.sum())
             # the classify clock stops here, before emission/store
-            # bookkeeping — exactly where the pre-frontier driver stopped it,
-            # so bench_fused_pipeline's classify-speedup history stays
-            # comparable
+            # bookkeeping, so ``time_classify`` times classification alone
             ls.time_classify += time.perf_counter() - ct0
         ls.skipped_absent_uniform += n_skipped
 

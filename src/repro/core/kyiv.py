@@ -175,7 +175,8 @@ class KyivConfig:
     double_buffer: bool = True  # overlap host candidate gen with device batches
     # run candidate generation, support tests and emit/store partitioning on
     # the placement's device (core.frontier); False pins the host reference
-    # path even for device placements — the bench_frontier baseline
+    # path even for device placements — the reference the tests hold the
+    # device frontier to
     device_frontier: bool = True
 
 
@@ -216,8 +217,8 @@ class LevelStats:
 
     def timing_breakdown(self) -> dict:
         """JSON-friendly per-level stage clocks on the host (served in
-        ``/stats`` and recorded by the benchmarks). Device time is not among
-        them: it comes from the profiler's trace."""
+        ``/stats``). Device time is not among them: it comes from the
+        profiler's trace."""
         return {
             "k": self.k,
             "total": self.time_total,

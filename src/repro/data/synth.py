@@ -48,7 +48,7 @@ def exposed_dataset(
 
     Unlike ``randomized_dataset`` (where QI counts explode with n at τ=1),
     the number of planted QIs scales linearly and mining stays cheap, so
-    record-coverage and planner benchmarks can run at paper-scale row counts.
+    record-coverage and planner runs can use paper-scale row counts.
     """
     rng = np.random.default_rng(seed)
     out = rng.integers(0, base_domain, size=(n, m)).astype(np.int64)
@@ -115,7 +115,7 @@ def pumsb_like(n: int = 49_046, m: int = 74, seed: int = 0) -> np.ndarray:
 
 def poker_like(n: int = 1_000_000, m: int = 10, seed: int = 0) -> np.ndarray:
     """Poker-hand analogue: 5 cards x (suit in {1..4}, rank in {1..13}),
-    drawn without replacement within a hand — 117 items like the original."""
+    drawn without replacement within a hand — 5 x 4 + 5 x 13 = 85 items."""
     rng = np.random.default_rng(seed)
     # sample 5 distinct cards out of 52 per row, vectorised
     cards = np.argsort(rng.random((n, 52)), axis=1)[:, :5]

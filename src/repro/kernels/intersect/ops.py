@@ -6,8 +6,8 @@ buckets (so device executables are reused across levels and batches),
 dispatches to one of the engines and strips padding:
 
 * ``numpy``  — host vectorised ``np.bitwise_and`` + popcount (``np.bitwise_count``
-  on numpy>=2.0, an exact ``unpackbits`` fallback otherwise); fastest on this
-  CPU-only container, used by the wall-clock benchmarks.
+  on numpy>=2.0, an exact ``unpackbits`` fallback otherwise); the host
+  reference engine.
 * ``jnp``    — the jnp oracle under jit (XLA CPU/TPU).
 * ``pallas`` — the Pallas kernels (interpreted on the CPU backend, compiled by
   Mosaic on the TPU — ``repro.core.placement.resolve_interpret`` decides).
@@ -546,8 +546,8 @@ class LevelPipeline:
     With ``fused_classify=True`` the per-pair class codes are produced by the
     placement itself (device classification for jnp/pallas/mesh); with
     ``False`` the handle returns ``classes=None`` and the caller re-derives
-    the masks on the host — kept as the comparison baseline for
-    ``benchmarks/bench_fused_pipeline.py``.
+    the masks on the host, or needs only the counts: the incremental
+    recount and delta-born counts (``service/incremental.py``) run this way.
     """
 
     def __init__(

@@ -1,8 +1,7 @@
 # Fleet launch environment for serve_miner (source before launching).
 #
 # Allocator settings for multi-host runs: tcmalloc for the host-side bitset
-# churn. `repro.launch.mesh.launch_env_summary` records the resulting
-# environment into bench JSON rows so every perf number names it.
+# churn.
 #
 # Usage:
 #   source launch/env.sh

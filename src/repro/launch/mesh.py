@@ -1,43 +1,23 @@
-"""Production meshes and multi-host launch plumbing.
-
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state. Single-pod: 16x16 = 256
-chips, axes (data, model). Multi-pod: 2 pods x 256 = 512 chips with a leading
-"pod" axis — the pod axis extends data parallelism across the inter-pod
-links (DCN in practice; the dry-run proves the sharding is coherent).
+"""Meshes and multi-host launch plumbing for the mining service.
 
 Multi-host specs add a leading **dcn** axis over processes:
 ``mesh_from_spec("2x4x1")`` is 2-way DCN data parallelism x 4-way in-host
 pair sharding x 1-way word sharding. ``distributed_init`` wires the
-process into the fleet (`jax.distributed.initialize`), ``is_main`` is the
-HomebrewNLP-Jax-style coordinator gate (only process 0 binds HTTP / owns
-artifact writes), and ``launch_env_summary`` snapshots the launch/XLA flag
-environment (``launch/env.sh``) into bench JSONs so perf rows stay
-reproducible.
+process into the fleet (`jax.distributed.initialize`), and ``is_main`` is
+the HomebrewNLP-Jax-style coordinator gate (only process 0 binds HTTP /
+owns artifact writes).
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 __all__ = [
-    "make_production_mesh",
     "make_host_mesh",
     "mesh_from_spec",
     "distributed_init",
     "is_main",
-    "launch_env_summary",
 ]
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes
-    )
 
 
 def mesh_from_spec(spec: str) -> jax.sharding.Mesh:
@@ -114,20 +94,3 @@ def is_main() -> bool:
     and merges fleet answers; everyone else runs the peer command loop."""
     return int(jax.process_index()) == 0
 
-
-def launch_env_summary() -> dict:
-    """The launch environment that shaped this process's performance:
-    recorded verbatim into bench JSON rows (``benchmarks/bench_mesh.py``)
-    so every multi-host perf claim carries the XLA/allocator config that
-    produced it (see ``launch/env.sh``)."""
-    return {
-        "backend": jax.default_backend(),
-        "process_count": int(jax.process_count()),
-        "local_devices": int(jax.local_device_count()),
-        "xla_flags": os.environ.get("XLA_FLAGS", ""),
-        "ld_preload": os.environ.get("LD_PRELOAD", ""),
-        "jax_platforms": os.environ.get("JAX_PLATFORMS", ""),
-        "tcmalloc_report_threshold": os.environ.get(
-            "TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD", ""
-        ),
-    }

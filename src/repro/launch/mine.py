@@ -17,7 +17,7 @@ from ..core import KyivConfig, itemize, mine, preprocess
 from ..core.kyiv import mine_preprocessed
 from ..data.loaders import read_fimi
 from ..data.synth import DATASETS
-from ..distributed.checkpoint import CheckpointManager
+from ..service.checkpoint import CheckpointManager
 from .compile_cache import configure_compile_cache
 
 

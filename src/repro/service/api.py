@@ -49,7 +49,7 @@ from ..obs.trace import current_trace_id as _obs_current_trace_id
 from ..obs.trace import span as _obs_span
 from ..obs.trace import start_trace as _obs_start_trace
 from ..obs.trace import watch_compiles as _obs_watch_compiles
-from ..distributed.checkpoint import CheckpointManager
+from .checkpoint import CheckpointManager
 from ..kernels.intersect import LevelPipeline
 from ..sampling import SamplingConfig, build_sample, classify_counts
 from ..sampling.refine import recount_supports

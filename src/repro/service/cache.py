@@ -6,8 +6,7 @@ answer is computed; engines are validated bit-identical, so results are
 shared across them). Entries keep the full :class:`MiningResult`, which
 serves three roles:
 
-* repeat queries at the current version return instantly (the ≥20x warm
-  path in ``benchmarks/bench_service.py``);
+* repeat queries at the current version return without re-mining;
 * the newest entry for the same ``(tau, kmax, ordering)`` at an *older*
   version is the base the incremental miner recounts against after appends;
 * quasi-identifier reports are derived from cached results without

@@ -13,7 +13,7 @@ Two pieces:
     Owns the :class:`DatasetStore` plus its WAL and periodic snapshots.
     Every ``snapshot_every`` appends the full store state
     (:meth:`DatasetStore.export_state`) is folded into an atomic
-    :class:`~repro.distributed.checkpoint.CheckpointManager` checkpoint and
+    :class:`~repro.service.checkpoint.CheckpointManager` checkpoint and
     the WAL is reset, bounding both replay time and log size.
     :meth:`DurableStore.recover` rebuilds the store bit-identically —
     same item ids, bitsets, version watermarks — from the newest intact
@@ -43,7 +43,7 @@ import zlib
 
 import numpy as np
 
-from ..distributed.checkpoint import CheckpointManager
+from .checkpoint import CheckpointManager
 from ..obs import metrics as _om
 from ..obs.trace import span as _obs_span
 from .faults import NULL_INJECTOR, FaultInjector

@@ -1,4 +1,4 @@
-"""Fault tolerance: checkpoint manager semantics + mining/training resume."""
+"""Fault tolerance: checkpoint manager semantics + mining resume."""
 
 import os
 
@@ -7,7 +7,7 @@ import pytest
 
 from repro.core import KyivConfig, itemize, mine, preprocess
 from repro.core.kyiv import mine_preprocessed
-from repro.distributed.checkpoint import CheckpointManager, load_pytree, save_pytree
+from repro.service.checkpoint import CheckpointManager, load_pytree, save_pytree
 
 
 def test_pytree_roundtrip(tmp_path):

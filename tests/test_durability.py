@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import bits_to_rows
-from repro.distributed.checkpoint import CheckpointManager, save_pytree
+from repro.service.checkpoint import CheckpointManager, save_pytree
 from repro.service import (
     DatasetStore,
     DurableStore,
