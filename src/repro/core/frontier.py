@@ -196,7 +196,9 @@ class LevelFrontier:
     def as_level(self, *, host_bits: bool = False) -> Level:
         bits = self.bits
         if host_bits and bits is not None and not isinstance(bits, np.ndarray):
+            # (t, W) whatever the device layout: pad words, if any, are zero
             bits = np.asarray(bits)
+            bits = bits.reshape(bits.shape[0], -1)
         return Level(k=self.k, itemsets=self.itemsets, counts=self.counts, bits=bits)
 
     @classmethod
@@ -235,6 +237,16 @@ def _device_frontier_capable(placement, pipe, config) -> bool:
         and hasattr(placement, "frontier_dispatch")
         and hasattr(pipe, "submit_padded")
     )
+
+
+def _dispatch_span(pipe, pairs: int, write_children: bool):
+    """The ``intersect.dispatch`` span of one batch; ``word_blocks`` is the
+    indexed kernels' grid steps per pair, where the pipeline runs them."""
+    attrs = {"pairs": pairs}
+    blocks = getattr(pipe, "word_blocks", None)
+    if blocks is not None and (n := blocks(write_children)) is not None:
+        attrs["word_blocks"] = n
+    return _obs_span("intersect.dispatch", **attrs)
 
 
 def _wait(ls, site: str, fetch, *args):
@@ -502,7 +514,7 @@ def _advance_host(
             continue
         pairs = np.stack([cand.i_idx[sel], cand.j_idx[sel]], axis=1).astype(np.int32)
         it0 = time.perf_counter()
-        with _obs_span("intersect.dispatch", pairs=len(sel)):
+        with _dispatch_span(pipe, len(sel), write_children):
             handle = pipe.submit(pairs, write_children)  # async dispatch
         ls.time_intersect += time.perf_counter() - it0
         entry = (cand.itemsets[sel], pairs, handle)
@@ -680,7 +692,7 @@ def _advance_device(
                 continue
             sel_pairs = np.ascontiguousarray(pairs_h[keep])
             it0 = time.perf_counter()
-            with _obs_span("intersect.dispatch", pairs=int(keep.sum())):
+            with _dispatch_span(pipe, int(keep.sum()), write_children):
                 handle = pipe.submit(sel_pairs, write_children)
             ls.time_intersect += time.perf_counter() - it0
             entry = ("host", lpos[keep], sel_pairs, handle)
@@ -690,7 +702,7 @@ def _advance_device(
                 cpairs, n_ok_dev = placement.frontier_mask(fstate, pairs_d, ok_d)
                 ls.time_candidates += time.perf_counter() - ct0
             it0 = time.perf_counter()
-            with _obs_span("intersect.dispatch", pairs=n_pairs):
+            with _dispatch_span(pipe, n_pairs, write_children):
                 handle = pipe.submit_padded(cpairs, n_pairs, write_children)
             ls.time_intersect += time.perf_counter() - it0
             entry = ("dev", n_pairs, cpairs, n_ok_dev, handle)

@@ -389,9 +389,11 @@ class DevicePlacement:
     With the Pallas engine the word axis is padded to a multiple of 128
     lanes (``store_word_tile``: the dataset store builds bitsets that way,
     so only foreign callers pay a pad), and the indexed kernels read the
-    ``(t, 1, W)`` row layout (``kernels.intersect.intersect.as_rows``):
-    :meth:`prepare` converts a level once, and the kernels hand children
-    back in that layout so the next level chains without a copy.
+    dense ``(t, S, 128)`` row layout
+    (``kernels.intersect.intersect.as_dense_rows``, W padded to a multiple
+    of 1,024 words): :meth:`prepare` converts a level once, and the kernels
+    hand children back in that layout so the next level chains without a
+    copy. The coverage kernel keeps the ``(t, 1, W)`` layout of ``as_rows``.
     ``interpret`` is resolved from the backend (:func:`resolve_interpret`).
     """
 
@@ -423,27 +425,37 @@ class DevicePlacement:
         # unsupported (warning + copy), so gate on backend.
         self.donate = jax.default_backend() in ("tpu", "gpu")
 
-    def _resident(self, bits):
-        """``(array, owned)``: bits on the device in this placement's layout
-        (lane-padded words, row layout for the indexed kernels); ``owned``
-        when this call created the array, so :meth:`release` may drop it."""
+    def _resident(self, bits, layout=None):
+        """``(array, owned, n_words)``: bits on the device, lane-padded for
+        the Pallas engine and then in ``layout`` when given; ``owned`` when
+        this call created the array, so :meth:`release` may drop it;
+        ``n_words`` the lane-padded words of a row before ``layout``."""
         out = bits if isinstance(bits, jax.Array) else jnp.asarray(bits)
         if self.engine == "pallas":
             out = _ops.pad_words(out)
-            if self._row_layout:
-                out = _kernels.as_rows(out)
-        return out, out is not bits
+        n_words = int(np.prod(out.shape[1:]))
+        if layout is not None:
+            out = layout(out)
+        return out, out is not bits, n_words
 
     def prepare(self, bits, parent_counts, tau: int, *, fused_classify: bool):
-        dev, owned = self._resident(bits)
+        dev, owned, n_words = self._resident(
+            bits, _kernels.as_dense_rows if self._row_layout else None
+        )
         return (
             dev,
             jnp.asarray(np.asarray(parent_counts), dtype=jnp.int32),
             jnp.int32(int(tau)),
-            int(dev.shape[-1]),
+            n_words,
             fused_classify,
             owned,
         )
+
+    def word_blocks(self, state, write_children: bool) -> int | None:
+        """Grid steps per pair of the indexed kernels on a prepared level."""
+        if not self._row_layout:
+            return None
+        return _kernels.row_blocks(int(state[0].shape[1]), write_children)
 
     def padded_size(self, m: int, *, pad_buckets: bool = True) -> int:
         return _ops.next_bucket(m) if pad_buckets else m
@@ -508,7 +520,7 @@ class DevicePlacement:
         return jnp.asarray(bits)
 
     def prepare_coverage(self, bits):
-        return self._resident(bits)[0]
+        return self._resident(bits, _kernels.as_rows if self._row_layout else None)[0]
 
     def coverage_dispatch(self, state, padded_sets, padded_weights):
         _guard("coverage")

@@ -8,9 +8,13 @@ Two data paths, each with a *write* and a *count-only* variant:
    HBM into VMEM. The row *gather* is thereby fused into the block fetch — no
    gathered copy of the parent level is ever materialised in HBM. This is the
    TPU analogue of the paper's "intersection directly on the stored level".
-   Rows are read from the ``(t, 1, W)`` row layout (:func:`as_rows`) and
-   per-pair counts/classes leave as 128-lane rows: the only block shapes the
-   chip's compiler accepts for one row and one scalar per pair.
+   Rows are read from the dense ``(t, S, 128)`` row layout
+   (:func:`as_dense_rows`): a row is one block of S sublanes, S a multiple
+   of 8, so every vector op works on full (8, 128) tiles and a pair takes
+   one grid step. While consecutive pairs share their I row (the pairs come
+   i-sorted) the I block index does not change and Pallas skips its fetch.
+   Per-pair counts/classes leave as 128-lane rows: the only block shape the
+   chip's compiler accepts for one scalar per pair.
 
 2. **Gathered** (`*_gathered`): operates on pre-gathered ``(M, W)`` operand
    matrices with ``(block_pairs, block_words)`` VMEM tiles — the layout- and
@@ -43,9 +47,10 @@ can ``nonzero`` directly — the classify contract consumed by
 ``repro.core.kyiv`` when ``KyivConfig.fused_classify`` is on.
 
 On the CPU backend the kernels are interpreted; on a TPU Mosaic compiles
-them (``repro.core.placement.resolve_interpret`` decides, once). Word blocks
-are multiples of 128 lanes — callers pad W (``ops.pad_words``) — and
-``tests/test_tpu_compile.py`` compiles every variant for a described v5e.
+them (``repro.core.placement.resolve_interpret`` decides, once). The gathered
+kernels' word blocks are multiples of 128 lanes — callers pad W
+(``ops.pad_words``) — and ``tests/test_tpu_compile.py`` compiles every
+variant for a described v5e.
 """
 
 from __future__ import annotations
@@ -61,6 +66,9 @@ from .ref import CLASS_EMIT, CLASS_SKIP, CLASS_STORE
 
 __all__ = [
     "as_rows",
+    "as_dense_rows",
+    "dense_sublanes",
+    "row_blocks",
     "intersect_write_indexed",
     "intersect_count_indexed",
     "intersect_write_gathered",
@@ -87,23 +95,84 @@ def _classify_scalar(cnt, minp, tau):
 
 
 def as_rows(bits: jax.Array) -> jax.Array:
-    """The indexed kernels' **row layout**: ``(t, W)`` -> ``(t, 1, W)``.
+    """The coverage kernel's **row layout**: ``(t, W)`` -> ``(t, 1, W)``.
 
     Mosaic tiles the last two dims of a block by (8, 128), so a single-row
     ``(1, bw)`` block of a 2-D ``(t, W)`` array is refused on the chip.
     With the row axis leading, one row is the block ``(None, 1, bw)`` whose
     last two dims are legal (1 equals the full dim, bw is a lane multiple).
-    The reshape is a relayout copy on the chip, so placements convert a
-    level once when it becomes resident — the kernels keep their child
-    output in this layout and the next level chains without a copy.
     """
     return bits if bits.ndim == 3 else bits.reshape(bits.shape[0], 1, bits.shape[1])
 
 
-def _row_spec(bw: int, col: int) -> pl.BlockSpec:
-    # one parent row per pair side, its index read from the flattened
+_ROW_TILE = _SUBLANES * _LANES  # words of one full (8, 128) uint32 tile
+
+# VMEM that one grid step's row blocks may take, double buffers included:
+# half of v5e's 16 MiB scoped VMEM, the rest left to Mosaic. A poker row
+# (31,744 words) is 127 KB, six buffers of it under 0.8 MB.
+ROW_VMEM_BYTES = 8 << 20
+
+
+def dense_sublanes(n_words: int) -> int:
+    """S of the dense row layout: ``ceil(W / 128)`` rounded up to a multiple
+    of 8 sublanes, i.e. W padded to a multiple of 1,024 words."""
+    return -(-int(n_words) // _ROW_TILE) * _SUBLANES
+
+
+def as_dense_rows(bits: jax.Array) -> jax.Array:
+    """The indexed kernels' **dense row layout**: ``(t, W)`` -> ``(t, S, 128)``.
+
+    Pad words are zeros, so every popcount is unchanged. With S a multiple
+    of 8, XLA lays the array out in (8, 128) tiles of one row each: a row is
+    contiguous in HBM and one block of full vregs in VMEM. The conversion is
+    a relayout copy on the chip, so placements convert a level once when it
+    becomes resident; the write kernels hand children back in this layout
+    and the next level chains without a copy. A 3-D array is taken to be
+    dense already and passes through.
+    """
+    if bits.ndim == 3:
+        return bits
+    t, w = bits.shape
+    s = dense_sublanes(w)
+    if s * _LANES != w:
+        bits = jnp.pad(bits, ((0, 0), (0, s * _LANES - w)))
+    return bits.reshape(t, s, _LANES)
+
+
+def _largest_divisor(n: int, cap: int) -> int:
+    """Largest divisor of ``n`` that is <= ``cap`` (at least 1), from the
+    divisor pairs up to the square root of ``n``."""
+    if n <= cap:
+        return n
+    best, d = 1, 1
+    while d * d <= n:
+        if n % d == 0:
+            for c in (d, n // d):
+                if best < c <= cap:
+                    best = c
+        d += 1
+    return best
+
+
+def _block_sublanes(s: int, write: bool, vmem_bytes: int) -> int:
+    """Sublanes of one row block: the whole row S when two operands (and,
+    on the write path, the child), each double-buffered, fit ``vmem_bytes``;
+    else the largest multiple of 8 dividing S that fits."""
+    buffers = 2 * (3 if write else 2)
+    cap = max(1, vmem_bytes // (buffers * _ROW_TILE * 4))  # in 8-sublane tiles
+    return _SUBLANES * _largest_divisor(s // _SUBLANES, cap)
+
+
+def row_blocks(s: int, write: bool, vmem_bytes: int = ROW_VMEM_BYTES) -> int:
+    """Grid steps per pair of the indexed kernels over rows of S sublanes:
+    1 unless a whole row overflows the VMEM budget."""
+    return s // _block_sublanes(s, write, vmem_bytes)
+
+
+def _row_spec(sb: int, col: int) -> pl.BlockSpec:
+    # one parent row block per pair side, its row read from the flattened
     # scalar-prefetched pair table (2m: I parent, 2m + 1: J parent)
-    return pl.BlockSpec((None, 1, bw), lambda m, j, idx, *_: (idx[2 * m + col], 0, j))
+    return pl.BlockSpec((None, sb, _LANES), lambda m, j, idx, *_: (idx[2 * m + col], j, 0))
 
 
 # Per-pair scalars (count, class) leave the kernel as one 128-lane row per
@@ -114,19 +183,20 @@ _LANE_ROW_SPEC = pl.BlockSpec((None, 1, _LANES), lambda m, j, *_: (m, 0, 0))
 
 
 def _indexed_kernel(write: bool, classify: bool):
-    """Kernel body for one (pair, word-block) grid step.
+    """Kernel body for one (pair, row-block) grid step.
 
-    The AND + popcount runs on the pair's two ``(1, bw)`` row blocks; the
-    count accumulates across word blocks in the pair's lane row, and the
-    fused variants classify on the final word block (``minp`` and ``tau``
+    The AND + popcount runs on the pair's two ``(sb, 128)`` row blocks; the
+    popcounts fold into an (8, 128) int32 accumulator with vreg adds, and
+    the last row block reduces it once to the pair's count, written to its
+    lane row (and classified on the fused variants: ``minp`` and ``tau``
     ride in SMEM).
     """
 
     def kernel(*refs):
         if classify:
-            _idx_ref, minp_ref, tau_ref, a_ref, b_ref, *outs = refs
+            _idx_ref, minp_ref, tau_ref, a_ref, b_ref, *outs, acc_ref = refs
         else:
-            _idx_ref, a_ref, b_ref, *outs = refs
+            _idx_ref, a_ref, b_ref, *outs, acc_ref = refs
         if write:
             child_ref, *outs = outs
         cnt_ref = outs[0]
@@ -135,49 +205,51 @@ def _indexed_kernel(write: bool, classify: bool):
         w = jnp.bitwise_and(a_ref[...], b_ref[...])
         if write:
             child_ref[...] = w
-        pc = jnp.sum(jax.lax.population_count(w).astype(jnp.int32), axis=1, keepdims=True)
+        pc = jax.lax.population_count(w).astype(jnp.int32)
+        part = jnp.sum(pc.reshape(-1, _SUBLANES, _LANES), axis=0)
 
         @pl.when(j == 0)
         def _init():
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        cnt_ref[...] += jnp.broadcast_to(pc, cnt_ref.shape)
+        acc_ref[...] += part
 
-        if classify:
-            cls_ref = outs[1]
-
-            # classification runs once, on the pair's final word block, when
-            # the accumulated popcount is complete
-            @pl.when(j == pl.num_programs(1) - 1)
-            def _classify():
-                cls_ref[...] = _classify_scalar(cnt_ref[...], minp_ref[m], tau_ref[0])
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _finish():
+            lanes = jnp.sum(acc_ref[...], axis=0, keepdims=True)
+            cnt = jnp.broadcast_to(jnp.sum(lanes, axis=1, keepdims=True), cnt_ref.shape)
+            cnt_ref[...] = cnt
+            if classify:
+                outs[1][...] = _classify_scalar(cnt, minp_ref[m], tau_ref[0])
 
     return kernel
 
 
-def _indexed_call(bits, pairs, minp, tau, *, name, write, bw, interpret):
+def _indexed_call(bits, pairs, minp, tau, *, name, write, interpret,
+                  vmem_bytes=ROW_VMEM_BYTES):
     """Shared pallas_call of the four indexed variants; ``name`` is the
     kernel's name in the profiler's trace.
 
-    ``bits`` is ``(t, W)`` or already in :func:`as_rows` layout; the child
-    comes back in the caller's layout. ``minp``/``tau`` are None for the
-    unfused variants. Scalar prefetch holds the flattened ``(2M,)`` pair
-    table (an ``(M, 2)`` table pads its last dim to 128 lanes in SMEM) plus,
-    fused, the ``(M,)`` per-pair min parent count and ``tau``.
+    ``bits`` is ``(t, W)`` or already in :func:`as_dense_rows` layout; the
+    child comes back in the caller's layout (``(M, W)`` for 2-D input). The
+    grid is ``(M, row_blocks)``: one step per pair unless a row overflows
+    ``vmem_bytes``. ``minp``/``tau`` are None for the unfused variants.
+    Scalar prefetch holds the flattened ``(2M,)`` pair table (an ``(M, 2)``
+    table pads its last dim to 128 lanes in SMEM) plus, fused, the ``(M,)``
+    per-pair min parent count and ``tau``.
     """
-    rows = as_rows(bits)
-    _, _, W = rows.shape
+    rows = as_dense_rows(bits)
+    _, S, _ = rows.shape
     M = pairs.shape[0]
-    if W % bw:
-        raise ValueError(f"W={W} not divisible by block_words={bw}")
+    sb = _block_sublanes(S, write, vmem_bytes)
     classify = minp is not None
     scalars = [pairs.astype(jnp.int32).reshape(-1)]
     if classify:
         scalars += [minp.astype(jnp.int32), jnp.asarray(tau, jnp.int32).reshape(1)]
     out_specs, out_shape = [], []
     if write:
-        out_specs.append(pl.BlockSpec((None, 1, bw), lambda m, j, *_: (m, 0, j)))
-        out_shape.append(jax.ShapeDtypeStruct((M, 1, W), rows.dtype))
+        out_specs.append(pl.BlockSpec((None, sb, _LANES), lambda m, j, *_: (m, j, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((M, S, _LANES), rows.dtype))
     for _ in range(2 if classify else 1):
         out_specs.append(_LANE_ROW_SPEC)
         out_shape.append(jax.ShapeDtypeStruct((M, 1, _LANES), jnp.int32))
@@ -185,9 +257,10 @@ def _indexed_call(bits, pairs, minp, tau, *, name, write, bw, interpret):
         _indexed_kernel(write, classify),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(M, W // bw),
-            in_specs=[_row_spec(bw, 0), _row_spec(bw, 1)],
+            grid=(M, S // sb),
+            in_specs=[_row_spec(sb, 0), _row_spec(sb, 1)],
             out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((_SUBLANES, _LANES), jnp.int32)],
         ),
         out_shape=out_shape,
         interpret=interpret,
@@ -196,7 +269,7 @@ def _indexed_call(bits, pairs, minp, tau, *, name, write, bw, interpret):
     if write:
         child, *outs = outs
         if bits.ndim == 2:
-            child = child.reshape(M, W)
+            child = child.reshape(M, S * _LANES)[:, : bits.shape[1]]
         return (child, *(o[:, 0, 0] for o in outs))
     return tuple(o[:, 0, 0] for o in outs)
 
@@ -206,41 +279,39 @@ def _pair_minp(parent_counts, pairs):
     return jnp.minimum(pc[pairs[:, 0]], pc[pairs[:, 1]])
 
 
-@functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def intersect_write_indexed(
     bits: jax.Array,
     pairs: jax.Array,
     *,
-    block_words: int = 1024,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """child = bits[pairs[:,0]] & bits[pairs[:,1]]; counts = popcount(child).
 
     Args:
-      bits: (t, W) uint32 parent-level bitsets, or their (t, 1, W) row layout.
+      bits: (t, W) uint32 parent-level bitsets, or their dense (t, S, 128)
+        row layout (:func:`as_dense_rows`).
       pairs: (M, 2) int32 row indices.
-      block_words: word-dimension VMEM tile (a multiple of 128 dividing W).
     Returns:
-      (child (M, W) uint32 — (M, 1, W) for row-layout input, counts (M,) int32)
+      (child (M, W) uint32 — (M, S, 128) for dense input, counts (M,) int32)
     """
     return _indexed_call(
         bits, pairs, None, None, name="intersect_write_indexed", write=True,
-        bw=min(block_words, bits.shape[-1]), interpret=interpret,
+        interpret=interpret,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def intersect_count_indexed(
     bits: jax.Array,
     pairs: jax.Array,
     *,
-    block_words: int = 2048,
     interpret: bool = False,
 ) -> jax.Array:
     """Count-only k=k_max path: popcount(bits[i] & bits[j]) with no HBM child write."""
     (cnt,) = _indexed_call(
         bits, pairs, None, None, name="intersect_count_indexed", write=False,
-        bw=min(block_words, bits.shape[-1]), interpret=interpret,
+        interpret=interpret,
     )
     return cnt
 
@@ -338,20 +409,20 @@ def intersect_count_gathered(
     return cnt[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def intersect_classify_write_indexed(
     bits: jax.Array,
     pairs: jax.Array,
     parent_counts: jax.Array,
     tau: jax.Array,
     *,
-    block_words: int = 1024,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused child + popcount + class code, gather via scalar-prefetch.
 
     Args:
-      bits: (t, W) uint32 parent-level bitsets, or their (t, 1, W) row layout.
+      bits: (t, W) uint32 parent-level bitsets, or their dense (t, S, 128)
+        row layout.
       pairs: (M, 2) int32 row indices.
       parent_counts: (t,) int32 parent popcounts |R_I|; the per-pair minimum
         is gathered here and rides in SMEM, so SMEM use grows with the batch,
@@ -362,26 +433,23 @@ def intersect_classify_write_indexed(
     """
     return _indexed_call(
         bits, pairs, _pair_minp(parent_counts, pairs), tau,
-        name="intersect_classify_write_indexed", write=True,
-        bw=min(block_words, bits.shape[-1]), interpret=interpret,
+        name="intersect_classify_write_indexed", write=True, interpret=interpret,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def intersect_classify_count_indexed(
     bits: jax.Array,
     pairs: jax.Array,
     parent_counts: jax.Array,
     tau: jax.Array,
     *,
-    block_words: int = 2048,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused count-only k=k_max path: (counts, classes), no HBM child write."""
     return _indexed_call(
         bits, pairs, _pair_minp(parent_counts, pairs), tau,
-        name="intersect_classify_count_indexed", write=False,
-        bw=min(block_words, bits.shape[-1]), interpret=interpret,
+        name="intersect_classify_count_indexed", write=False, interpret=interpret,
     )
 
 

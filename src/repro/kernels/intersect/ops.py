@@ -35,12 +35,13 @@ Two dispatch surfaces:
   output onto it.
 
 Locality-aware pair scheduling: :func:`locality_order` sorts a batch's pairs
-by ``(i, j)`` so the indexed kernel's scalar-prefetch DMA re-fetches each
-parent row once per *run* of equal ``i`` instead of once per pair; outputs
-are un-permuted before the caller sees them. The default candidate generator
-already emits ``i``-sorted batches, so the common case is a single O(M)
-monotonicity check — the sort only triggers for externally supplied pair
-lists (sharded re-balancing, resumed checkpoints, tests).
+by ``(i, j)`` so the indexed kernel's scalar-prefetch DMA fetches each I
+parent row once per *run* of equal ``i`` instead of once per pair (a row is
+one block of the dense layout, so its block index holds across the run);
+outputs are un-permuted before the caller sees them. The default candidate
+generator already emits ``i``-sorted batches, so the common case is a single
+O(M) monotonicity check — the sort only triggers for externally supplied
+pair lists (sharded re-balancing, resumed checkpoints, tests).
 
 Padding contract: pair index rows added for padding point at row 0 twice; a
 self-pair is *uniform* (count == min parent count), so fused classify marks
@@ -68,6 +69,12 @@ _PIPE_PAIRS = _om.counter(
     "repro_intersect_pairs_total",
     "Pairs dispatched through the level pipeline (padding included for "
     "mode=padded).",
+    ("mode",),
+)
+_PIPE_STEPS = _om.counter(
+    "repro_intersect_grid_steps_total",
+    "Grid steps of the indexed Pallas kernels for the pairs counted in "
+    "repro_intersect_pairs_total: row blocks per pair times pairs.",
     ("mode",),
 )
 _LEVELS_RETIRED = _om.counter(
@@ -160,25 +167,11 @@ def pad_words(bits, multiple: int = LANES):
 def _largest_divisor_tile(dim: int, preferred: int) -> int:
     """Largest word tile <= max(preferred, 128) that divides ``dim`` and is
     a multiple of 128 lanes — the only tiles the chip's compiler accepts for
-    a word block. ``dim`` must itself be lane-aligned (:func:`pad_words`).
-
-    Enumerates divisor pairs of ``dim // 128`` up to its square root, so
-    prime word counts cost microseconds, not a linear scan.
-    """
+    a word block of the gathered and coverage kernels. ``dim`` must itself
+    be lane-aligned (:func:`pad_words`)."""
     if dim % LANES:
         raise ValueError(f"word count {dim} is not a multiple of {LANES} lanes")
-    n, cap = dim // LANES, max(preferred // LANES, 1)
-    if n <= cap:
-        return dim
-    best = 1
-    d = 1
-    while d * d <= n:
-        if n % d == 0:
-            for c in (d, n // d):
-                if best < c <= cap:
-                    best = c
-        d += 1
-    return best * LANES
+    return _k._largest_divisor(dim // LANES, max(preferred // LANES, 1)) * LANES
 
 
 def _pair_tile(bucket: int, block_pairs: int) -> int:
@@ -293,20 +286,17 @@ def intersect_and_count(
         from ...core.placement import resolve_interpret  # core imports kernels
 
         interpret = resolve_interpret(interpret)
-        bits_j = pad_words(bits_j)
-        W = bits_j.shape[1]
-        bw = _largest_divisor_tile(W, block_words)
         if indexed:
             if write_children:
                 child, cnt = _k.intersect_write_indexed(
-                    bits_j, pairs_j, block_words=bw, interpret=interpret
+                    bits_j, pairs_j, interpret=interpret
                 )
             else:
                 child = None
-                cnt = _k.intersect_count_indexed(
-                    bits_j, pairs_j, block_words=bw, interpret=interpret
-                )
+                cnt = _k.intersect_count_indexed(bits_j, pairs_j, interpret=interpret)
         else:
+            bits_j = pad_words(bits_j)
+            bw = _largest_divisor_tile(bits_j.shape[1], block_words)
             a = bits_j[pairs_j[:, 0]]
             b = bits_j[pairs_j[:, 1]]
             bm = _pair_tile(bucket, block_pairs)
@@ -436,36 +426,32 @@ def build_engine_dispatch(
     if engine != "pallas":
         raise ValueError(f"engine must be jnp|pallas, got {engine!r}")
 
-    # pallas
-    bw = _largest_divisor_tile(n_words, block_words)
+    # pallas; the indexed kernels block a row by its own width, not block_words
     if indexed:
         if fused_classify:
             if write_children:
                 return lambda bits, pairs_j, pc, tau: _k.intersect_classify_write_indexed(
-                    bits, pairs_j, pc, tau, block_words=bw, interpret=interpret
+                    bits, pairs_j, pc, tau, interpret=interpret
                 )
             return lambda bits, pairs_j, pc, tau: (
                 None,
                 *_k.intersect_classify_count_indexed(
-                    bits, pairs_j, pc, tau, block_words=bw, interpret=interpret
+                    bits, pairs_j, pc, tau, interpret=interpret
                 ),
             )
         if write_children:
             return lambda bits, pairs_j, pc, tau: (
-                *_k.intersect_write_indexed(
-                    bits, pairs_j, block_words=bw, interpret=interpret
-                ),
+                *_k.intersect_write_indexed(bits, pairs_j, interpret=interpret),
                 None,
             )
         return lambda bits, pairs_j, pc, tau: (
             None,
-            _k.intersect_count_indexed(
-                bits, pairs_j, block_words=bw, interpret=interpret
-            ),
+            _k.intersect_count_indexed(bits, pairs_j, interpret=interpret),
             None,
         )
 
     # gathered pallas path
+    bw = _largest_divisor_tile(n_words, block_words)
     bm = _pair_tile(bucket, block_pairs)
     if fused_classify:
         if write_children:
@@ -583,7 +569,9 @@ class LevelPipeline:
         self.fused_classify = fused_classify
         self.locality_sort = locality_sort
         self.pad_buckets = pad_buckets
-        self.n_words = int(bits.shape[1])
+        # words of a row: the logical width of (t, W) input; a level already
+        # in the dense (t, S, 128) layout carries only its padded width
+        self.n_words = int(np.prod(bits.shape[1:]))
         self._state = placement.prepare(
             bits, parent_counts, self.tau, fused_classify=fused_classify
         )
@@ -600,6 +588,19 @@ class LevelPipeline:
             release = getattr(self.placement, "release", None)
             if release is not None:
                 release(state)
+
+    def word_blocks(self, write_children: bool) -> int | None:
+        """Grid steps per pair of this level's indexed kernels (1 unless a
+        row overflows VMEM), or None where the placement runs none."""
+        blocks = getattr(self.placement, "word_blocks", None)
+        return None if blocks is None else blocks(self._state, write_children)
+
+    def _count(self, mode: str, pairs: int, write_children: bool) -> None:
+        _PIPE_BATCHES.inc(mode=mode)
+        _PIPE_PAIRS.inc(pairs, mode=mode)
+        blocks = self.word_blocks(write_children)
+        if blocks is not None:
+            _PIPE_STEPS.inc(pairs * blocks, mode=mode)
 
     def _dispatch(self, padded, write_children: bool):
         """One placement dispatch per chunk of at most the placement's
@@ -637,8 +638,8 @@ class LevelPipeline:
             counts = np.asarray(cnt_d)[:m].astype(np.int64)
             child = None
             if child_d is not None:
-                # row-layout (M, 1, W) children flatten for free on the host;
-                # lane/shard pad words are sliced off
+                # dense-layout (M, S, 128) children flatten for free on the
+                # host; layout/shard pad words are sliced off
                 child = np.asarray(child_d)
                 child = child.reshape(child.shape[0], -1)[:m, :n_words]
             classes = np.asarray(cls_d)[:m].astype(np.int32) if cls_d is not None else None
@@ -661,8 +662,7 @@ class LevelPipeline:
         ``result()``'s strip; ``raw()`` exposes the padded placement-native
         outputs for device-side partitioning.
         """
-        _PIPE_BATCHES.inc(mode="padded")
-        _PIPE_PAIRS.inc(int(pairs.shape[0]), mode="padded")
+        self._count("padded", int(pairs.shape[0]), write_children)
         raw = self._dispatch(pairs, write_children)
         return BatchHandle(self._materializer(*raw, m), raw=raw)
 
@@ -678,8 +678,7 @@ class LevelPipeline:
             out = (child, np.zeros(0, dtype=np.int64), classes)
             return BatchHandle(lambda: out)
 
-        _PIPE_BATCHES.inc(mode="host")
-        _PIPE_PAIRS.inc(m, mode="host")
+        self._count("host", m, write_children)
         pairs = np.ascontiguousarray(pairs, dtype=np.int32)
         order = inverse = None
         if self.locality_sort:

@@ -75,6 +75,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import math
 import time
 
 import numpy as np
@@ -570,8 +571,13 @@ def _device_supports(
             child = write(prefixes)
             if child.sharding != resident.sharding:  # a mesh shards pairs too
                 child = jax.device_put(child, resident.sharding)
-            items = resident.reshape((n_items,) + tuple(child.shape[1:]))
-            mat, left = jnp.concatenate([items, child]), n_items + inv
+            # the item rows in the child's layout: zero pad words up to its
+            # row width (the dense Pallas layout pads W), then its shape
+            row = tuple(child.shape[1:])
+            pad = math.prod(row) - int(resident.shape[1])
+            items = jnp.pad(resident, ((0, 0), (0, pad))) if pad else resident
+            mat = jnp.concatenate([items.reshape((n_items,) + row), child])
+            left = n_items + inv
         stats["rows_read"] += len(np.unique(np.concatenate([left, sets[:, -1]])))
         stats["pairs"] += len(sets)
         return pipeline(mat), np.stack([left, sets[:, -1]], axis=1).astype(np.int32)

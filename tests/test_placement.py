@@ -117,7 +117,7 @@ def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
 
 def test_pallas_placement_lane_pads_and_bounds_dispatch():
     """The Pallas placement pads words to 128 lanes (the store tiles for it),
-    keeps levels in the kernels' row layout, and LevelPipeline splits
+    keeps levels in the kernels' dense row layout, and LevelPipeline splits
     batches above the SMEM bound into chunks — results unchanged."""
     from repro.kernels.intersect.ops import MAX_INDEXED_PAIRS
 
@@ -134,7 +134,7 @@ def test_pallas_placement_lane_pads_and_bounds_dispatch():
     want_child = bits[pairs[:, 0]] & bits[pairs[:, 1]]
     p.max_dispatch_pairs = 128  # 300 pairs pad to 384 -> three dispatches
     pipe = LevelPipeline(bits, pc, tau=40, placement=p)
-    assert pipe._state[0].shape == (6, 1, 128)  # lane-padded row layout
+    assert pipe._state[0].shape == (6, 8, 128)  # 5 words pad to one (8, 128) tile
     child, counts, classes = pipe.submit(pairs, True).result()
     assert np.array_equal(child, want_child)
     assert np.array_equal(counts, np.bitwise_count(want_child).sum(1))

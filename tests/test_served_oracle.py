@@ -64,10 +64,19 @@ def service():
         svc.close()
 
 
-@pytest.mark.parametrize("kmax", [1, 2, 3])
-@pytest.mark.parametrize("tau", [1, 3, 8])
-@pytest.mark.parametrize("engine", ["numpy", "jnp"])
-@pytest.mark.parametrize("name", list(TABLES))
+# every table on the host and jnp engines at each τ and depth, and on the
+# Pallas kernels (interpreted here) at kmax = 3, the depth that chains a
+# written level into a count-only one
+CASES = [
+    (name, engine, tau, kmax)
+    for name in TABLES
+    for engine in ("numpy", "jnp")
+    for tau in (1, 3, 8)
+    for kmax in (1, 2, 3)
+] + [(name, "pallas", 3, 3) for name in TABLES]
+
+
+@pytest.mark.parametrize("name,engine,tau,kmax", CASES)
 def test_served_cold_mine_matches_oracle(service, name, engine, tau, kmax):
     resp = service(name, engine).mine(tau=tau, kmax=kmax)
     assert resp.source == "cold"
@@ -83,3 +92,25 @@ def test_served_cold_mine_matches_oracle(service, name, engine, tau, kmax):
     for s in reply["itemsets"]:
         cols, values = np.asarray(s["items"]).T
         assert s["count"] == int(np.all(table[:, cols] == values, axis=1).sum())
+
+
+def test_served_append_then_incremental_mine_on_pallas():
+    """``/append`` then ``/mine`` on the Pallas kernels answers incremental,
+    with the itemsets and counts of a cold numpy mine of the whole table."""
+    base, delta = _table("poker"), poker_like(n=40, seed=2)
+    svc = MiningService.from_dataset(base, engine="pallas")
+    try:
+        assert svc.mine(tau=3, kmax=3).source == "cold"
+        svc.append(delta)
+        resp = svc.mine(tau=3, kmax=3)
+        assert resp.source == "incremental"
+        assert resp.info["device_words"] == svc.store.n_words
+        got = sorted(resp.result.as_value_sets())
+    finally:
+        svc.close()
+    cold = MiningService.from_dataset(np.concatenate([base, delta]), engine="numpy")
+    try:
+        want = sorted(cold.mine(tau=3, kmax=3).result.as_value_sets())
+    finally:
+        cold.close()
+    assert got == want

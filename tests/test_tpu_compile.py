@@ -5,9 +5,11 @@ described ``v5e:2x2`` topology and refuses what the chip would refuse —
 block shapes off the (8, 128) tiling, scalar-prefetch tables that overflow
 SMEM, VMEM tiles that do not fit. Shapes are the 1,000,000-row table the
 chip smoke serves: 31,250 words per bitset, lane-padded by the store to
-31,360, tiled at the placement's default word block, with pair buckets the
-level batch cap picks at that width (``core/frontier.py``). Interpret-mode
-tests cannot see any of this.
+31,360; the indexed kernels read it as dense (t, 248, 128) rows, the
+gathered and coverage kernels in tiles of the placement's default word
+block, with pair buckets the level batch cap picks at that width
+(``core/frontier.py``). The append cell's store, grown once to 35,328
+words, is compiled too. Interpret-mode tests cannot see any of this.
 
 The topology is described inside a module fixture (never at import): only
 one process may load libtpu at a time, and the worker that runs this file
@@ -41,6 +43,10 @@ W = -(-WORDS // LANES) * LANES  # as the store pads it for the Pallas engine
 BW = _largest_divisor_tile(
     W, inspect.signature(DevicePlacement).parameters["block_words"].default
 )
+S = _k.dense_sublanes(W)  # 248 sublanes: 31,744 words, a 1.2% pad
+# the append cell's store after its one growth (9/8 of the rows, tiled)
+W_APPEND = 35_328
+S_APPEND = _k.dense_sublanes(W_APPEND)  # 280
 # pairs per level batch at this width (core/frontier.py), as a bucket
 PAIR_BUCKET = min(next_bucket(max(4096, (1 << 28) // W)), MAX_INDEXED_PAIRS)
 
@@ -71,7 +77,35 @@ def _compile(fn, sharding, *shapes):
 def test_real_width_is_lane_tiled():
     assert W == 31_360 and W % LANES == 0
     assert BW % LANES == 0 and W % BW == 0
+    assert (S, S_APPEND) == (248, 280)
     assert PAIR_BUCKET * 3 <= SMEM_PREFETCH_WORDS  # pairs + per-pair min count
+
+
+def _pallas_grids(fn, shapes):
+    """The grid of every ``pallas_call`` that tracing ``fn`` reaches."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for p in eqn.params.values():
+                sub = getattr(p, "jaxpr", p)
+                if hasattr(sub, "eqns"):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(s, d) for s, d in shapes]).jaxpr)
+    return grids
+
+
+def _assert_dense_one_step_per_pair(fn, compiled, shapes):
+    """The level parameter is laid out in (8, 128) tiles of one row each,
+    and the kernel's grid takes one step per pair."""
+    (t, s, _), _ = shapes[0]
+    m = shapes[1][0][0]
+    layout = compiled.as_text().split("entry_computation_layout={", 1)[1].split("}}", 1)[0]
+    assert f"u32[{t},{s},128]{{2,1,0:T(8,128)" in layout, layout
+    assert _pallas_grids(fn, shapes) == [(m, 1)]
 
 
 @pytest.mark.parametrize("write", [True, False], ids=["write", "count"])
@@ -79,18 +113,26 @@ def test_fused_indexed_kernel_compiles(one_chip, write):
     u32, i32 = jnp.uint32, jnp.int32
     if write:  # level 1 -> 2: the 85 item rows, write the children
         t, m = N_ITEMS, LEVEL2
-        fn = lambda b, p, c, tau: _k.intersect_classify_write_indexed(  # noqa: E731
-            b, p, c, tau, block_words=BW
-        )
+        fn = _k.intersect_classify_write_indexed
     else:  # level 2 -> 3 (k = kmax): count-only over the stored children
         t, m = LEVEL2, PAIR_BUCKET
-        fn = lambda b, p, c, tau: _k.intersect_classify_count_indexed(  # noqa: E731
-            b, p, c, tau, block_words=BW
-        )
-    compiled = _compile(
-        fn, one_chip, ((t, 1, W), u32), ((m, 2), i32), ((t,), i32), ((), i32)
-    )
+        fn = _k.intersect_classify_count_indexed
+    shapes = (((t, S, LANES), u32), ((m, 2), i32), ((t,), i32), ((), i32))
+    compiled = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_dense_one_step_per_pair(fn, compiled, shapes)
+
+
+@pytest.mark.parametrize("write", [True, False], ids=["write", "count"])
+def test_append_indexed_kernel_compiles(one_chip, write):
+    """The append cell's unfused kernels over the store grown to 35,328
+    words: item rows plus the triples' prefix children, one dispatch chunk."""
+    u32, i32 = jnp.uint32, jnp.int32
+    fn = _k.intersect_write_indexed if write else _k.intersect_count_indexed
+    shapes = (((N_ITEMS + LEVEL2, S_APPEND, LANES), u32), ((PAIR_BUCKET, 2), i32))
+    compiled = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_dense_one_step_per_pair(fn, compiled, shapes)
 
 
 @pytest.mark.parametrize("write", [True, False], ids=["write", "count"])
@@ -126,9 +168,11 @@ def test_oversized_pair_table_is_a_compile_refusal(one_chip):
     from repro.core.placement import is_compile_refusal, is_device_failure
 
     m = 1 << 18  # 2 MiB of flattened pairs against 1 MiB of SMEM
-    fn = lambda b, p: _k.intersect_count_indexed(b, p, block_words=BW)  # noqa: E731
     with pytest.raises(jax.errors.JaxRuntimeError) as err:
-        _compile(fn, one_chip, ((64, 1, W), jnp.uint32), ((m, 2), jnp.int32))
+        _compile(
+            _k.intersect_count_indexed, one_chip,
+            ((64, S, LANES), jnp.uint32), ((m, 2), jnp.int32),
+        )
     assert "RESOURCE_EXHAUSTED" in str(err.value)
     assert is_compile_refusal(err.value) and not is_device_failure(err.value)
 
@@ -136,20 +180,17 @@ def test_oversized_pair_table_is_a_compile_refusal(one_chip):
 def _kernel_cases():
     u32, i32 = jnp.uint32, jnp.int32
     w, bw, t, m = 8 * LANES, 4 * LANES, 64, 128
-    rows, flat, pairs = ((t, 1, w), u32), ((m, w), u32), ((m, 2), i32)
+    dense, rows = ((t, w // LANES, LANES), u32), ((t, 1, w), u32)
+    flat, pairs = ((m, w), u32), ((m, 2), i32)
     cnt, tau = ((t,), i32), ((), i32)
     minp = ((m,), i32)
     return [
-        ("intersect_write_indexed",
-         lambda b, p: _k.intersect_write_indexed(b, p, block_words=bw), (rows, pairs)),
-        ("intersect_count_indexed",
-         lambda b, p: _k.intersect_count_indexed(b, p, block_words=bw), (rows, pairs)),
-        ("intersect_classify_write_indexed",
-         lambda b, p, c, x: _k.intersect_classify_write_indexed(b, p, c, x, block_words=bw),
-         (rows, pairs, cnt, tau)),
-        ("intersect_classify_count_indexed",
-         lambda b, p, c, x: _k.intersect_classify_count_indexed(b, p, c, x, block_words=bw),
-         (rows, pairs, cnt, tau)),
+        ("intersect_write_indexed", _k.intersect_write_indexed, (dense, pairs)),
+        ("intersect_count_indexed", _k.intersect_count_indexed, (dense, pairs)),
+        ("intersect_classify_write_indexed", _k.intersect_classify_write_indexed,
+         (dense, pairs, cnt, tau)),
+        ("intersect_classify_count_indexed", _k.intersect_classify_count_indexed,
+         (dense, pairs, cnt, tau)),
         ("intersect_write_gathered",
          lambda a, b: _k.intersect_write_gathered(a, b, block_words=bw), (flat, flat)),
         ("intersect_count_gathered",
